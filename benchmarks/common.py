@@ -65,13 +65,17 @@ def profile_cnn(name: str, *, batch: int = 32, train_steps: int = 12,
     """Measure one zoo model: flops/bytes (XLA), wall time, short-train acc.
 
     Profiles are cached (in-process + on disk) — fig2/fig4/fig6 all profile
-    the same zoo, and compiles dominate the cost on this host.
+    the same zoo, and compiles dominate the cost.  The key names the device
+    (platform and ``device_kind``), so a profile timed on one device is
+    never read back on another.
     """
     import json as _json
-    key = (name, batch, train_steps, seed)
+    dev = jax.devices()[0]
+    device = f"{dev.platform}-{dev.device_kind}".replace(" ", "_")
+    key = (name, batch, train_steps, seed, device)
     if key in _PROFILE_CACHE:
         return _PROFILE_CACHE[key]
-    fkey = _CACHE_DIR / f"{name}_{batch}_{train_steps}_{seed}.json"
+    fkey = _CACHE_DIR / f"{name}_{batch}_{train_steps}_{seed}_{device}.json"
     if fkey.exists():
         run = ModelRun(**_json.loads(fkey.read_text()))
         _PROFILE_CACHE[key] = run
@@ -100,8 +104,7 @@ def _profile_cnn_uncached(name: str, *, batch: int = 32, train_steps: int = 12,
     x0, y0 = data.batch_at(0)
     lowered = step.lower(params, opt, jnp.asarray(x0), jnp.asarray(y0))
     compiled = lowered.compile()
-    from repro.launch.hloparse import xla_cost
-    cost = xla_cost(compiled)
+    cost = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
 

@@ -243,6 +243,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (chaos_serve, ctrl_overhead, decode_kernel,
                             decode_throughput, fig2_energy, fig3_overhead,
                             fig4_capping, fig5_edxp, fig6_tradeoff, kv_tier,

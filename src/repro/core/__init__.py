@@ -10,9 +10,9 @@ from repro.core.energy import (EnergyLedger, EnergyReport, PowerSample,
                                dram_power_estimate, integrate_power)
 from repro.core.fitting import FitResult, f_curve, fit_cost_curve, minimize_fit
 from repro.core.policy import BALANCED, ENERGY_LEAN, LATENCY_LEAN, QoSPolicy
-from repro.core.powermodel import (DEVICES, RTX_3080, RTX_3090, TPU_V5E,
+from repro.core.powermodel import (RTX_3080, RTX_3090, TPU_KINDS, TPU_V5E,
                                    DeviceSpec, PowerCappedDevice, StepEstimate,
-                                   WorkloadProfile)
+                                   WorkloadProfile, device_spec)
 from repro.core.powershift import (ClusterNode, NodeAllocation, ShiftPlan,
                                    allocate_power, detect_stragglers)
 from repro.core.profiler import (DEFAULT_CAP_GRID, CapDecision, CapProfiler,
@@ -28,7 +28,7 @@ __all__ = [
     "FitResult", "f_curve", "fit_cost_curve", "minimize_fit",
     "QoSPolicy", "ENERGY_LEAN", "BALANCED", "LATENCY_LEAN",
     "DeviceSpec", "PowerCappedDevice", "StepEstimate", "WorkloadProfile",
-    "DEVICES", "RTX_3080", "RTX_3090", "TPU_V5E",
+    "RTX_3080", "RTX_3090", "TPU_KINDS", "TPU_V5E", "device_spec",
     "ClusterNode", "NodeAllocation", "ShiftPlan", "allocate_power",
     "detect_stragglers",
     "CapDecision", "CapProfiler", "RecordingBackend", "DEFAULT_CAP_GRID",

@@ -72,7 +72,23 @@ TPU_V5E = DeviceSpec(
     vmem_bytes=16 * 2**20, hbm_bytes=16 * 2**30,
 )
 
-DEVICES: dict[str, DeviceSpec] = {d.name: d for d in (RTX_3080, RTX_3090, TPU_V5E)}
+# The chips a run may find itself on, keyed by ``jax.Device.device_kind``.
+TPU_KINDS: dict[str, DeviceSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def device_spec(device) -> DeviceSpec:
+    """The spec of the device a run is on (``jax.devices()[0]``).
+
+    On a TPU the ``device_kind`` must be in ``TPU_KINDS``: an unknown chip
+    is an error, never a default.  Any other platform is a stand-in that
+    models the deployment target, the TPU v5e."""
+    if device.platform != "tpu":
+        return TPU_V5E
+    try:
+        return TPU_KINDS[device.device_kind]
+    except KeyError:
+        raise ValueError(f"no DeviceSpec for TPU kind {device.device_kind!r}; "
+                         f"known kinds: {sorted(TPU_KINDS)}") from None
 
 
 # --------------------------------------------------------------------------

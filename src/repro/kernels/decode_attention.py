@@ -146,11 +146,16 @@ def _write_partials(part_ref, lse_ref, m_ref, l_ref, acc_ref):
     softmax identically.  A split whose blocks were all masked (ring not
     yet wrapped, window, or the clamp padding of a non-divisible split
     count) still has ``l == 0`` — its lse is pinned to NEG_INF so the
-    merge weighs it to exactly zero instead of NaN-ing on log(0)."""
+    merge weighs it to exactly zero instead of NaN-ing on log(0).
+
+    The lse block is one ``(1, R)`` row: the chip's tiling rule requires
+    a block's last two dims to equal the array's (or divide (8, 128)), so
+    the lse array carries a unit dim before its rows and the wrapper drops
+    it."""
     l = l_ref[...]
     part_ref[0, 0, 0] = acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]
     lse_ref[0, 0, 0] = jnp.where(
-        l > 0.0, m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
+        l > 0.0, m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)[None, :]
 
 
 def _lse_merge_kernel(part_ref, lse_ref, o_ref):
@@ -363,8 +368,8 @@ def decode_attention_pallas_partials(
         out_specs=[
             pl.BlockSpec((1, 1, 1, 1, Dv),
                          lambda b, h, s, ik, pos_ref: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda b, h, s, ik, pos_ref: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, 1, 1, 1),
+                         lambda b, h, s, ik, pos_ref: (b, h, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1,), jnp.float32),       # running max m
@@ -372,13 +377,14 @@ def decode_attention_pallas_partials(
             pltpu.VMEM((1, Dv), jnp.float32),    # running numerator
         ],
     )
-    return pl.pallas_call(
+    partial, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hq, n_splits, 1, Dv), jnp.float32),
-                   jax.ShapeDtypeStruct((B, Hq, n_splits, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, Hq, n_splits, 1, 1), jnp.float32)],
         interpret=interpret,
     )(pos_arr, *inputs)
+    return partial, lse[:, :, :, 0]
 
 
 def decode_attention_pallas(
@@ -661,8 +667,8 @@ def verify_attention_pallas_partials(
         out_specs=[
             pl.BlockSpec((1, 1, 1, Q, Dv),
                          lambda b, h, s, ik, pos_ref: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q),
-                         lambda b, h, s, ik, pos_ref: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, 1, 1, Q),
+                         lambda b, h, s, ik, pos_ref: (b, h, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((Q,), jnp.float32),       # running max m, per query
@@ -670,13 +676,14 @@ def verify_attention_pallas_partials(
             pltpu.VMEM((Q, Dv), jnp.float32),    # running numerator
         ],
     )
-    return pl.pallas_call(
+    partial, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hq, n_splits, Q, Dv), jnp.float32),
-                   jax.ShapeDtypeStruct((B, Hq, n_splits, Q), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, Hq, n_splits, 1, Q), jnp.float32)],
         interpret=interpret,
     )(pos_arr, *inputs)
+    return partial, lse[:, :, :, 0]
 
 
 def verify_attention_pallas(
@@ -949,8 +956,8 @@ def paged_verify_attention_pallas_partials(
         out_specs=[
             pl.BlockSpec((1, 1, 1, Q, Dv),
                          lambda b, h, s, j, bt_ref, pos_ref: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q),
-                         lambda b, h, s, j, bt_ref, pos_ref: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, 1, 1, Q),
+                         lambda b, h, s, j, bt_ref, pos_ref: (b, h, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((Q,), jnp.float32),       # running max m, per query
@@ -958,13 +965,14 @@ def paged_verify_attention_pallas_partials(
             pltpu.VMEM((Q, Dv), jnp.float32),    # running numerator
         ],
     )
-    return pl.pallas_call(
+    partial, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hq, n_splits, Q, Dv), jnp.float32),
-                   jax.ShapeDtypeStruct((B, Hq, n_splits, Q), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, Hq, n_splits, 1, Q), jnp.float32)],
         interpret=interpret,
     )(bt, pos_arr, *inputs)
+    return partial, lse[:, :, :, 0]
 
 
 def paged_verify_attention_pallas(
@@ -1211,8 +1219,8 @@ def paged_decode_attention_pallas_partials(
         out_specs=[
             pl.BlockSpec((1, 1, 1, 1, Dv),
                          lambda b, h, s, j, bt_ref, pos_ref: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda b, h, s, j, bt_ref, pos_ref: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, 1, 1, 1),
+                         lambda b, h, s, j, bt_ref, pos_ref: (b, h, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1,), jnp.float32),       # running max m
@@ -1220,13 +1228,14 @@ def paged_decode_attention_pallas_partials(
             pltpu.VMEM((1, Dv), jnp.float32),    # running numerator
         ],
     )
-    return pl.pallas_call(
+    partial, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hq, n_splits, 1, Dv), jnp.float32),
-                   jax.ShapeDtypeStruct((B, Hq, n_splits, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, Hq, n_splits, 1, 1), jnp.float32)],
         interpret=interpret,
     )(bt, pos_arr, *inputs)
+    return partial, lse[:, :, :, 0]
 
 
 def paged_decode_attention_pallas(
@@ -1413,7 +1422,8 @@ def _mla_paged_decode_partials_kernel(bt_ref, pos_ref, q_ref, lat_ref,
         l = l_ref[...]
         part_ref[0, 0] = acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]
         lse_ref[0, 0] = jnp.where(
-            l > 0.0, m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
+            l > 0.0, m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)),
+            NEG_INF)[None, :]
 
 
 def mla_paged_decode_attention_pallas_partials(
@@ -1457,8 +1467,8 @@ def mla_paged_decode_attention_pallas_partials(
         out_specs=[
             pl.BlockSpec((1, 1, Hq, r_kv),
                          lambda b, s, j, bt_ref, pos_ref: (b, s, 0, 0)),
-            pl.BlockSpec((1, 1, Hq),
-                         lambda b, s, j, bt_ref, pos_ref: (b, s, 0)),
+            pl.BlockSpec((1, 1, 1, Hq),      # (1, Hq) row: see _write_partials
+                         lambda b, s, j, bt_ref, pos_ref: (b, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((Hq,), jnp.float32),      # running max m, per head
@@ -1471,13 +1481,13 @@ def mla_paged_decode_attention_pallas_partials(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, n_splits, Hq, r_kv), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_splits, Hq), jnp.float32)],
+            jax.ShapeDtypeStruct((B, n_splits, 1, Hq), jnp.float32)],
         interpret=interpret,
     )(bt, pos_arr, qt, lat_pages)
     # -> the canonical (B, Hq, S, 1, Dv) partials layout shared by the
     # merge contract and the ref oracle
     return (partial.transpose(0, 2, 1, 3)[:, :, :, None, :],
-            lse.transpose(0, 2, 1)[:, :, :, None])
+            lse[:, :, 0].transpose(0, 2, 1)[:, :, :, None])
 
 
 def mla_paged_decode_attention_pallas(

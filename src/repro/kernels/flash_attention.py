@@ -24,6 +24,7 @@ Validated in interpret mode against kernels/ref.py over shape/dtype sweeps
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -110,9 +111,13 @@ def flash_attention(
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     if Sq % block_q or Sk % block_k:
-        # graceful fallback for ragged shapes, matching the chunked path's
-        # behaviour (lazy import: ops imports this module lazily too)
-        from repro.kernels.ops import flash_attention_jnp
+        # ragged shapes run the chunked jnp path, with a warning (lazy
+        # import: ops imports this module lazily too)
+        from repro.kernels.ops import FallbackWarning, flash_attention_jnp
+        warnings.warn(
+            f"flash_attention: Sq={Sq}, Sk={Sk} do not tile into blocks of "
+            f"({block_q}, {block_k}); falling back to the jnp path",
+            FallbackWarning, stacklevel=2)
         return flash_attention_jnp(q, k, v, causal=causal, window=window,
                                    logit_cap=logit_cap, scale=scale,
                                    q_offset=q_offset)

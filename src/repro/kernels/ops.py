@@ -157,12 +157,19 @@ def _split_attend_jnp(s: jax.Array, vf: jax.Array, n_splits: int) -> jax.Array:
     return _lse_merge_jnp(partial, lse)
 
 
+class FallbackWarning(RuntimeWarning):
+    """A requested path ran as a different one: a Pallas kernel as jnp, or
+    an engine feature (int8 pages, host tier) as the plain layout.  Every
+    such fallback warns with this category, so a run that must prove the
+    requested path ran (``chip_smoke.py``) turns them all into errors."""
+
+
 _KPOS_FALLBACK_WARNED: set[str] = set()
 
 
 def _warn_k_pos_fallback(entry: str) -> None:
-    """One-time (per entry point) warning when a custom ``k_pos`` silently
-    costs the caller the Pallas fast path."""
+    """One-time (per entry point) warning when a custom ``k_pos`` costs the
+    caller the Pallas fast path."""
     if entry in _KPOS_FALLBACK_WARNED:
         return
     _KPOS_FALLBACK_WARNED.add(entry)
@@ -170,7 +177,7 @@ def _warn_k_pos_fallback(entry: str) -> None:
         f"{entry}: custom k_pos slot layout disables the Pallas decode "
         "kernel (it derives ring positions from pos, assuming the canonical "
         "slot = p % C layout); falling back to the jnp backend for this "
-        "call", RuntimeWarning, stacklevel=3)
+        "call", FallbackWarning, stacklevel=3)
 
 
 _KV_DTYPE_FALLBACK_WARNED: set[str] = set()
@@ -186,23 +193,7 @@ def warn_kv_dtype_fallback(family: str, reason: str) -> None:
     warnings.warn(
         f"kv_dtype=int8 requested for model family {family!r} but {reason}; "
         "falling back to unquantized (bfloat16) KV pools for this engine",
-        RuntimeWarning, stacklevel=3)
-
-
-_PAGED_FALLBACK_WARNED: set[str] = set()
-
-
-def warn_paged_fallback(name: str, feature: str) -> None:
-    """One-time (per config) warning when a model family cannot ride the
-    paged serving engine and silently falls back to the ring-cache loop,
-    naming the SPECIFIC blocking feature (mirrors
-    ``warn_kv_dtype_fallback``)."""
-    if name in _PAGED_FALLBACK_WARNED:
-        return
-    _PAGED_FALLBACK_WARNED.add(name)
-    warnings.warn(
-        f"config {name!r} falls back to the ring-cache serving loop: paged "
-        f"serving blocked by {feature}", RuntimeWarning, stacklevel=3)
+        FallbackWarning, stacklevel=3)
 
 
 # ==========================================================================

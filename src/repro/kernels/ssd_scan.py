@@ -21,6 +21,7 @@ in tests/test_kernels.py.
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -80,9 +81,14 @@ def ssd_pallas(
     return_state: bool = False, interpret: bool = False,
 ):
     """Same contract as ops.ssd_chunked_jnp; initial_state/return_state fall
-    back to the jnp path (the kernel is the steady-state training fast path)."""
+    back to the jnp path with a warning (the kernel is the steady-state
+    training fast path)."""
     if initial_state is not None or return_state:
         from repro.kernels import ops
+        warnings.warn(
+            "ssd_pallas: the kernel neither takes nor returns SSM state; "
+            "falling back to the chunked jnp path", ops.FallbackWarning,
+            stacklevel=2)
         return ops.ssd_chunked_jnp(x, dt, A, B_mat, C_mat, D, chunk=chunk,
                                    initial_state=initial_state,
                                    return_state=return_state)

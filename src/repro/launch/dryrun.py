@@ -144,7 +144,7 @@ def lower_cell(spec: ArchSpec, shape: Shape, mesh, step_cfg: StepConfig):
 
     mem = compiled.memory_analysis()
     from repro.launch import hloparse
-    cost = hloparse.xla_cost(compiled)
+    cost = compiled.cost_analysis()
     hlo = hloparse.analyze(compiled.as_text())
 
     record = {

@@ -215,12 +215,3 @@ def analyze(hlo_text: str) -> dict[str, Any]:
     out = rollup(comps, entry)
     out["n_computations"] = len(comps)
     return out
-
-
-def xla_cost(compiled) -> dict[str, float]:
-    """``compiled.cost_analysis()`` normalised across jax versions: recent
-    jax returns one dict, older versions a list of per-device dicts."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)

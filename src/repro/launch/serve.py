@@ -66,15 +66,13 @@ import numpy as np
 from repro.configs import get_arch
 from repro.control import CapApplied, EventBus, StepDone
 from repro.control.online import OnlineCapProfiler
-from repro.core import (BALANCED, PowerCappedDevice, QoSPolicy, TPU_V5E,
-                        WorkloadProfile)
+from repro.core import (BALANCED, PowerCappedDevice, QoSPolicy,
+                        WorkloadProfile, device_spec)
 from repro.core.profiler import RecordingBackend
 from repro.data import DataConfig, TokenBatches
-from repro.kernels import ops
-from repro.launch.mesh import make_host_mesh
 from repro.runtime.chaos import ChaosBus, FaultInjector
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault import ServingSupervisor
-from repro.runtime.sharding import build_rules
 from repro.runtime.speculate import get_drafter
 from repro.runtime.steps import (StepConfig, make_decode_loop,
                                  make_prefill_step,
@@ -130,7 +128,7 @@ class FrostPlane:
     def __init__(self, cfg, n_slots: int, edp_exponent: float):
         self.bus = EventBus()
         self.backend = RecordingBackend()
-        self.device = PowerCappedDevice(TPU_V5E)
+        self.device = PowerCappedDevice(device_spec(jax.devices()[0]))
         self.cfg = cfg
         self.n_slots = n_slots
         self.meter = AnalyticDeviceMeter(self.device,
@@ -191,19 +189,19 @@ class FrostPlane:
         self.profiler.close()
 
 
-def run_batch(args, cfg, step_cfg, rules, params, frost: FrostPlane | None) -> int:
+def run_batch(args, cfg, step_cfg, params, frost: FrostPlane | None) -> int:
     """Static-batch baseline: batched prefill + fused ring decode chunks."""
     greedy = args.temperature <= 0.0
     plen = args.shared_prefix_len + args.prompt_len
     max_len = plen + args.gen
-    prefill = jax.jit(make_prefill_step(cfg, step_cfg, rules, max_len=max_len))
+    prefill = jax.jit(make_prefill_step(cfg, step_cfg, max_len=max_len))
     chunk = max(1, args.decode_chunk)
     # ONE decode executable per run: the final ragged chunk is padded to
     # ``chunk`` and its overrun tokens discarded (the old path compiled a
     # second executable for the tail).  AOT-compiled so compile time never
     # lands in a chunk's measured duration.
     loop_fn = jax.jit(
-        make_decode_loop(cfg, step_cfg, rules, chunk, greedy=greedy,
+        make_decode_loop(cfg, step_cfg, n_tokens=chunk, greedy=greedy,
                          temperature=max(args.temperature, 1e-6)),
         donate_argnums=(1,))
     loop = None
@@ -243,7 +241,7 @@ def run_batch(args, cfg, step_cfg, rules, params, frost: FrostPlane | None) -> i
         drafter = get_drafter(args.drafter, args.spec_k)
         loop_fn = jax.jit(
             make_speculative_decode_loop(
-                cfg, step_cfg, rules, chunk, drafter=drafter, greedy=greedy,
+                cfg, step_cfg, n_steps=chunk, drafter=drafter, greedy=greedy,
                 temperature=max(args.temperature, 1e-6)),
             donate_argnums=(1,))
         ds = drafter.init_state(args.requests)
@@ -319,8 +317,7 @@ def run_batch(args, cfg, step_cfg, rules, params, frost: FrostPlane | None) -> i
     return 0
 
 
-def run_engine(args, cfg, step_cfg, rules, params,
-               frost: FrostPlane | None) -> int:
+def run_engine(args, cfg, step_cfg, params, frost: FrostPlane | None) -> int:
     """Continuous batching: Poisson arrivals into the paged-KV engine."""
     greedy = args.temperature <= 0.0
     max_len = args.shared_prefix_len + args.prompt_len + args.gen
@@ -379,7 +376,8 @@ def run_engine(args, cfg, step_cfg, rules, params,
 
     admission = None
     if args.power_budget > 0:
-        device = frost.device if frost is not None else PowerCappedDevice(TPU_V5E)
+        device = frost.device if frost is not None \
+            else PowerCappedDevice(device_spec(jax.devices()[0]))
         admission = EnergyAwareAdmission(
             device, lambda n: decode_workload(cfg, n, tokens_per_step=eff["tps"]),
             args.power_budget,
@@ -424,7 +422,7 @@ def run_engine(args, cfg, step_cfg, rules, params,
         elif ev.kind == "bus_delay":
             cbus.delay_next(max(1, ev.duration))
 
-    eng_kwargs = dict(step_cfg=step_cfg, rules=rules, on_chunk=on_chunk,
+    eng_kwargs = dict(step_cfg=step_cfg, on_chunk=on_chunk,
                       on_prefill=on_prefill, admission=admission,
                       injector=injector,
                       on_heartbeat=supervisor.on_heartbeat, on_fault=on_fault,
@@ -594,30 +592,23 @@ def main():
     ap.add_argument("--edp-exponent", type=float, default=2.0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     spec = get_arch(args.arch)
     cfg = spec.smoke if args.smoke else spec.config
     step_cfg = with_decode_policy(StepConfig(remat="none"),
                                   kv_splits=_parse_kv_splits(args.kv_splits),
                                   decode_k_chunk=max(1, args.decode_k_chunk))
-    mesh = make_host_mesh()
-    rules = build_rules(cfg, mesh) if mesh.devices.size > 1 else None
+    # one-device serving: no mesh and no sharding rules, so the launcher
+    # places everything on jax.devices()[0] even on a host with four chips
     params, _ = tfm.init_lm(jax.random.PRNGKey(args.seed), cfg)
 
     n_par = args.n_slots if args.traffic == "poisson" else args.requests
     frost = None if args.no_frost else FrostPlane(cfg, n_par, args.edp_exponent)
 
     if args.traffic == "poisson":
-        blockers = tfm.paged_cache_blockers(cfg)
-        if blockers:
-            # the capability router names the specific blocking feature;
-            # today the tuple is empty for every family in the zoo, but the
-            # seam keeps future configs serving (ring batch) instead of dying
-            ops.warn_paged_fallback(cfg.name, blockers[0])
-            rc = run_batch(args, cfg, step_cfg, rules, params, frost)
-        else:
-            rc = run_engine(args, cfg, step_cfg, rules, params, frost)
+        rc = run_engine(args, cfg, step_cfg, params, frost)
     else:
-        rc = run_batch(args, cfg, step_cfg, rules, params, frost)
+        rc = run_batch(args, cfg, step_cfg, params, frost)
     if frost is not None:
         frost.summary()
     return rc

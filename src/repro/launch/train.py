@@ -23,6 +23,7 @@ Real-TPU deployments additionally want the XLA latency-hiding scheduler:
 from __future__ import annotations
 
 import argparse
+import tempfile
 import time
 
 import jax
@@ -30,17 +31,18 @@ import jax
 from repro.configs import get_arch
 from repro.control import CapApplied, EventBus, StepDone
 from repro.control.online import OnlineCapProfiler
-from repro.core import (CapProfiler, PowerCappedDevice, QoSPolicy, TPU_V5E,
-                        WorkloadProfile)
+from repro.core import (CapProfiler, PowerCappedDevice, QoSPolicy,
+                        WorkloadProfile, device_spec)
 from repro.core.profiler import RecordingBackend
 from repro.checkpoint import CheckpointManager
 from repro.data import DataConfig, TokenBatches
 from repro.launch.mesh import make_host_mesh
 from repro.models.config import ModelConfig
 from repro.optim import OptimizerConfig
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault import Supervisor, SupervisorConfig
 from repro.runtime.sharding import build_rules
-from repro.runtime.steps import StepConfig, init_train_state, make_train_step
+from repro.runtime.steps import StepConfig, init_train_state, jit_train_step
 from repro.telemetry.meters import AnalyticDeviceMeter, CpuProcessMeter, DramMeter
 from repro.telemetry.sampler import PowerSampler
 
@@ -55,7 +57,7 @@ def profile_cap_for_step(cfg: ModelConfig, flops: float, bytes_hbm: float,
                          hbm_bytes_per_step=bytes_hbm,
                          collective_bytes_per_step=coll,
                          samples_per_step=1)
-    dev = PowerCappedDevice(TPU_V5E)
+    dev = PowerCappedDevice(device_spec(jax.devices()[0]))
 
     class _W:                                   # Workload protocol adapter
         def probe(self, cap, duration_s):
@@ -76,7 +78,8 @@ def main():
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--n-micro", type=int, default=1)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint directory (default: a fresh temp dir)")
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--frost", action="store_true",
                     help="run the FROST control plane (batch profile warm-"
@@ -85,6 +88,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     spec = get_arch(args.arch)
     cfg = spec.smoke if args.smoke else spec.config
     print(f"[train] arch={cfg.name} params={cfg.param_count()/1e6:.1f}M "
@@ -101,12 +105,15 @@ def main():
 
     key = jax.random.PRNGKey(args.seed)
     state, axes = init_train_state(key, cfg, step_cfg)
-    train_step = jax.jit(make_train_step(cfg, step_cfg, rules),
-                         donate_argnums=(0,))
-
     data = TokenBatches(DataConfig(
         seed=args.seed, vocab_size=cfg.vocab_size, seq_len=args.seq,
         global_batch=args.batch, n_codebooks=cfg.n_codebooks))
+    train_step, state, place_batch = jit_train_step(
+        cfg, step_cfg, rules, state, axes, data.batch(0), donate=True)
+    batch_at = lambda i: place_batch(data.batch(i))
+    if rules is not None:
+        print(f"[train] mesh {dict(mesh.shape)} over "
+              f"{len(mesh.devices.flat)} devices")
 
     # -- FROST control plane (paper Sec III-C + Fig 1 loop) --------------------
     bus = EventBus()
@@ -118,10 +125,10 @@ def main():
                            edp_exponent=args.edp_exponent)
         # derive roofline terms from one compiled step
         from repro.launch import hloparse
-        lowered = train_step.lower(state, data.batch(0))
+        lowered = train_step.lower(state, batch_at(0))
         compiled = lowered.compile()
         h = hloparse.analyze(compiled.as_text())
-        ca = hloparse.xla_cost(compiled)
+        ca = compiled.cost_analysis()
         decision, frost_wl, frost_dev = profile_cap_for_step(
             cfg, h["dot_flops"], float(ca.get("bytes accessed", 0.0)),
             h["collective_bytes"], policy, bus=bus, backend=backend)
@@ -144,7 +151,8 @@ def main():
     sampler = PowerSampler(meters, rate_hz=0.1, bus=bus, node_id="node-0")
 
     # -- supervised run ----------------------------------------------------------
-    ckpt = CheckpointManager(args.ckpt_dir, keep=2, save_async=True)
+    ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="repro_ckpt_")
+    ckpt = CheckpointManager(ckpt_dir, keep=2, save_async=True)
     ckpt.save(state, 0)                    # recovery floor before step 1
     sup = Supervisor(
         SupervisorConfig(checkpoint_every=args.ckpt_every),
@@ -176,7 +184,7 @@ def main():
                                  energy_j=energy_j, model_id=cfg.name))
         return state, metrics
 
-    batches = (data.batch(i) for i in range(args.steps))
+    batches = (batch_at(i) for i in range(args.steps))
     t0 = time.time()
     with sampler:
         state, report = sup.run(instrumented_step, state, batches)

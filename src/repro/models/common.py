@@ -17,21 +17,6 @@ Params = dict[str, Any]
 
 
 # --------------------------------------------------------------------------
-# jax version compatibility
-# --------------------------------------------------------------------------
-def shard_map(fn, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions: the new top-level API takes
-    ``check_vma``; 0.4.x exposes ``jax.experimental.shard_map`` with the
-    equivalent ``check_rep`` knob."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
-
-
-# --------------------------------------------------------------------------
 # dtype helpers
 # --------------------------------------------------------------------------
 def dt(name: str) -> jnp.dtype:

@@ -233,12 +233,12 @@ def moe_forward(params, x, cfg: ModelConfig, ctx: ParallelCtx = NO_MESH,
         shared_specs = {"wi_gate": P(None, maxis), "wi_up": P(None, maxis),
                         "wo": P(maxis, None)}
 
-    out2d = common.shard_map(
+    out2d = jax.shard_map(
         shard_fn, mesh=ctx.mesh,
         in_specs=(bspec, bspec, bspec,
                   P(maxis), P(maxis), P(maxis), shared_specs),
         out_specs=bspec,
-        check=False,
+        check_vma=False,
     )(x2d, gates, idx, params["wi_gate"], params["wi_up"], params["wo"],
       shared_p)
     return out2d.reshape(B, S, d), aux
@@ -388,13 +388,13 @@ def moe_forward_a2a(params, x, cfg: ModelConfig, ctx: ParallelCtx,
         shared_specs = {"wi_gate": P(None, maxis), "wi_up": P(None, maxis),
                         "wo": P(maxis, None)}
 
-    out2d = common.shard_map(
+    out2d = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(bspec, bspec, bspec,
                   P(daxis, None, maxis), P(daxis, None, maxis),
                   P(daxis, maxis, None), shared_specs),
         out_specs=bspec,
-        check=False,
+        check_vma=False,
     )(x2d, gates, idx, params["wi_gate"], params["wi_up"], params["wo"],
       shared_p)
     return out2d.reshape(B, S, d), aux

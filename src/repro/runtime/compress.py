@@ -75,8 +75,7 @@ def make_compressed_dp_allreduce(mesh, axis: str = "pod"):
         def inner(g, e):
             return compressed_psum(g, axis, e)
         spec = jax.tree.map(lambda _: P(), grads)
-        from repro.models.common import shard_map
-        return shard_map(inner, mesh=mesh,
-                         in_specs=(spec, spec), out_specs=(spec, spec),
-                         check=False)(grads, error_state)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(spec, spec), out_specs=(spec, spec),
+                             check_vma=False)(grads, error_state)
     return fn

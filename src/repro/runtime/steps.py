@@ -17,6 +17,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.kernels.ops import KernelPolicy
 from repro.models import transformer as tfm
@@ -24,7 +25,8 @@ from repro.models.config import ModelConfig
 from repro.models.layers import NO_MESH
 from repro.models.transformer import RunCtx
 from repro.optim import OptimizerConfig, adamw_init, adamw_update
-from repro.runtime.sharding import ShardingRules
+from repro.optim.adamw import opt_state_sharding
+from repro.runtime.sharding import ShardingRules, batch_sharding
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,12 +78,14 @@ def init_train_state(key, cfg: ModelConfig, step_cfg: StepConfig):
     return {"params": params, "opt": opt, "step": jnp.zeros((), jnp.int32)}, axes
 
 
-def train_state_sharding(rules: ShardingRules, axes_tree) -> dict[str, Any]:
-    from jax.sharding import NamedSharding, PartitionSpec
+def train_state_sharding(rules: ShardingRules, axes_tree,
+                         state: dict[str, Any]) -> dict[str, Any]:
+    """NamedShardings for ``init_train_state``'s state: params by the
+    rules, optimizer moments mirroring them, counters replicated."""
     psh = rules.param_sharding(axes_tree)
-    rep = NamedSharding(rules.mesh, PartitionSpec())
-    opt_cfg_placeholder = {"count": rep, "mu": psh, "nu": psh}
-    return {"params": psh, "opt": opt_cfg_placeholder, "step": rep}
+    return {"params": psh,
+            "opt": opt_state_sharding(psh, state["opt"], rules.mesh),
+            "step": NamedSharding(rules.mesh, PartitionSpec())}
 
 
 def make_train_step(cfg: ModelConfig, step_cfg: StepConfig,
@@ -137,6 +141,32 @@ def make_train_step(cfg: ModelConfig, step_cfg: StepConfig,
         return new_state, metrics
 
     return train_step
+
+
+def jit_train_step(cfg: ModelConfig, step_cfg: StepConfig,
+                   rules: ShardingRules | None, state: dict[str, Any],
+                   axes_tree, batch0: Any, *, donate: bool = False):
+    """Jit the train step and place its inputs.
+
+    Returns ``(step, state, place_batch)``.  With ``rules`` the state is put
+    on the mesh with ``train_state_sharding`` and every batch with
+    ``batch_sharding``, and the step keeps those shardings on its inputs and
+    outputs (nothing lands on one device to be resharded inside the step).
+    ``rules=None`` is the plain one-device jit and leaves both where they
+    are.  ``batch0`` only lends its structure to the batch sharding."""
+    donate_argnums = (0,) if donate else ()
+    if rules is None:
+        step = jax.jit(make_train_step(cfg, step_cfg),
+                       donate_argnums=donate_argnums)
+        return step, state, lambda batch: batch
+    state_sh = train_state_sharding(rules, axes_tree, state)
+    bsh = batch_sharding(rules, batch0)
+    step = jax.jit(make_train_step(cfg, step_cfg, rules),
+                   in_shardings=(state_sh, bsh),
+                   out_shardings=(state_sh, None),
+                   donate_argnums=donate_argnums)
+    return (step, jax.device_put(state, state_sh),
+            lambda batch: jax.device_put(batch, bsh))
 
 
 def make_prefill_step(cfg: ModelConfig, step_cfg: StepConfig,

@@ -124,7 +124,7 @@ class EngineConfig:
     # quantized KV pages: "int8" stores every page pool as int8 with
     # per-row fp32 scales and the dequant fused into the split-KV sweeps
     # (see docs/prefix_cache.md, "KV memory hierarchy").  Dense-GQA
-    # families only — elsewhere the engine warns once (RuntimeWarning) and
+    # families only — elsewhere the engine warns once (FallbackWarning) and
     # keeps cache_dtype.  The default leaves the decode path byte-identical
     # to unquantized serving.
     kv_dtype: str = "bfloat16"
@@ -357,7 +357,7 @@ class ServeEngine:
                 warnings.warn(
                     f"config {cfg.name!r}: host KV tier disabled: no page "
                     "pool rides the block tables (state-slot layout)",
-                    RuntimeWarning, stacklevel=2)
+                    ops.FallbackWarning, stacklevel=2)
         self._ctx = make_run_ctx(cfg, rules, self.step_cfg)
         # AOT-compiled paged chunk loops, keyed (chunk_len, speculative):
         # graceful degradation swaps in a shorter / non-speculative loop

@@ -28,9 +28,10 @@ from repro.optim.adamw import opt_state_sharding
 from repro.runtime.sharding import batch_sharding, build_rules, cache_sharding
 from repro.runtime.steps import StepConfig, init_train_state, make_train_step, make_serve_step
 from jax.sharding import NamedSharding, PartitionSpec
+from repro.launch.mesh import make_mesh
 
 results = {}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 for arch_id in ["smollm-135m", "phi3.5-moe-42b-a6.6b", "mamba2-370m",
                 "zamba2-1.2b", "deepseek-v2-236b"]:
@@ -132,3 +133,36 @@ def test_sharded_equals_unsharded():
         dgrad_bound = 0.12 if r.get("route_limited") else 0.05
         assert r["dgrad"] < dgrad_bound, f"{arch} grad-norm mismatch: {r}"
         assert r["dparam"] < 2e-2, f"{arch} param mismatch: {r}"
+
+
+_LAUNCH = {
+    "serve": (["repro.launch.serve", "--traffic", "poisson", "--requests",
+               "4"], "[serve] engine: 4 requests"),
+    "train": (["repro.launch.train", "--steps", "3", "--seq", "32"],
+              "[train] 3 steps"),
+}
+
+
+@pytest.mark.parametrize("launcher", sorted(_LAUNCH))
+def test_launcher_runs_on_four_host_devices(launcher, tmp_path):
+    """Each launcher's CLI runs to its summary on a host that shows four
+    devices: serve keeps its one-device engine, train shards its step over
+    a mesh of all four."""
+    args, summary = _LAUNCH[launcher]
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
+    if launcher == "train":
+        args = args + ["--ckpt-dir", str(tmp_path / "ckpt")]
+    out = subprocess.run([sys.executable, "-m", *args, "--arch",
+                          "smollm-135m", "--smoke"], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, f"{launcher} failed:\n{out.stderr[-3000:]}"
+    assert summary in out.stdout, out.stdout[-3000:]
+    if launcher == "train":
+        assert "over 4 devices" in out.stdout, out.stdout[-3000:]
+    else:
+        finished = [l for l in out.stdout.splitlines() if "  rid=" in l]
+        assert len(finished) == 4, out.stdout[-3000:]
+        assert all("fin=max_new_tokens" in l for l in finished), finished

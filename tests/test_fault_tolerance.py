@@ -213,12 +213,11 @@ def test_compressed_psum_single_device_mesh():
     def inner(g, e):
         return compressed_psum(g, "pod", e)
 
-    from repro.models.common import shard_map
-    out, err = shard_map(
+    out, err = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(), g),) * 2,
         out_specs=(jax.tree.map(lambda _: P(), g),) * 2,
-        check=False)(g, e)
+        check_vma=False)(g, e)
     np.testing.assert_allclose(np.asarray(out["w"]), [0.5, -1.5, 2.0],
                                atol=0.02)
     # error feedback captured the quantization residual

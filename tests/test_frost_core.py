@@ -7,10 +7,29 @@ from repro.core import (BALANCED, ENERGY_LEAN, LATENCY_LEAN, CapProfiler,
                         ClusterNode, EnergyLedger, PowerCappedDevice,
                         PowerSample, QoSPolicy, RTX_3080, RTX_3090, TPU_V5E,
                         WorkloadProfile, allocate_power, detect_stragglers,
-                        dram_power_estimate, edp, f_curve, fit_cost_curve,
-                        integrate_power, minimize_fit, nelder_mead)
+                        device_spec, dram_power_estimate, edp, f_curve,
+                        fit_cost_curve, integrate_power, minimize_fit,
+                        nelder_mead)
 from repro.core.edp import CapMeasurement, normalized_costs
 from repro.core.simplex import minimize_scalar_on_interval
+
+
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", TPU_V5E),     # jax's device_kind for a v5e
+    ("cpu", "cpu", TPU_V5E),             # CPU stands in for the target
+    ("tpu", "TPU v9 mystery", None),     # unknown chip: an error
+])
+def test_device_spec_by_device_kind(platform, kind, want):
+    if want is None:
+        with pytest.raises(ValueError, match="TPU v9 mystery"):
+            device_spec(_Dev(platform, kind))
+    else:
+        assert device_spec(_Dev(platform, kind)) is want
 
 
 # --------------------------------------------------------------------------

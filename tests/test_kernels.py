@@ -379,11 +379,13 @@ def test_paged_verify_pallas_vs_full_attention(shape, dtype):
 
 def test_flash_pallas_ragged_fallback():
     """Ragged Sq/Sk no longer assert: the Pallas wrapper falls back to the
-    chunked jnp path, matching its behaviour."""
+    chunked jnp path, matching its behaviour, and says so with a
+    FallbackWarning."""
     q, k, v = _qkv(jax.random.PRNGKey(11), 1, 100, 100, 4, 2, 32, 32,
                    jnp.float32)
     o_ref = ref.attention_ref(q, k, v, causal=True)
-    o = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    with pytest.warns(ops.FallbackWarning, match="falling back"):
+        o = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
     np.testing.assert_allclose(o, o_ref, atol=2e-5, rtol=2e-5)
 
 
@@ -457,6 +459,25 @@ def test_ssd_pallas_vs_ref(shape):
     y_ref = ref.ssd_ref(x, dt, A, Bm, Cm, D)
     y = ssd_pallas(x, dt, A, Bm, Cm, D, chunk=chunk, interpret=True)
     np.testing.assert_allclose(y, y_ref, atol=2e-3, rtol=2e-3)
+
+
+def test_ssd_pallas_with_state_warns_and_matches_ref():
+    """The kernel carries no state in or out: asking for it runs the
+    chunked jnp path, with a FallbackWarning, and still matches the
+    exact scan."""
+    B, S, H, P, G, N, chunk = SSD_SHAPES[0]
+    ks = jax.random.split(jax.random.PRNGKey(8), 5)
+    x = jax.random.normal(ks[0], (B, S, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)))
+    Bm = jax.random.normal(ks[3], (B, S, G, N))
+    Cm = jax.random.normal(ks[4], (B, S, G, N))
+    y_ref, h_ref = ref.ssd_ref(x, dt, A, Bm, Cm, return_state=True)
+    with pytest.warns(ops.FallbackWarning, match="SSM state"):
+        y, h = ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk, return_state=True,
+                          interpret=True)
+    np.testing.assert_allclose(y, y_ref, atol=2e-3, rtol=2e-3)
+    np.testing.assert_allclose(h, h_ref, atol=2e-3, rtol=2e-3)
 
 
 def test_ssd_initial_state_and_decode_chain():

@@ -18,7 +18,6 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_SPECS, get_arch
-from repro.kernels import ops
 from repro.models import transformer as tfm
 from repro.runtime.steps import StepConfig, make_run_ctx
 from repro.serving import (EngineConfig, PagedKVCache, Request, ServeEngine,
@@ -94,20 +93,6 @@ def test_capability_routers_cover_the_zoo():
     win = get_arch("h2o-danube-3-4b").smoke
     assert "sliding_window" in tfm.int8_paged_blockers(win)
     assert tfm.int8_paged_blockers(get_arch("smollm-135m").smoke) == ()
-
-
-def test_warn_paged_fallback_warns_once():
-    """The ring-cache fallback warning fires ONCE per config and names the
-    blocking feature (mirrors ``warn_kv_dtype_fallback``)."""
-    ops._PAGED_FALLBACK_WARNED.discard("zoo-test-config")
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        ops.warn_paged_fallback("zoo-test-config", "uses_ssm")
-        ops.warn_paged_fallback("zoo-test-config", "uses_ssm")
-    msgs = [str(w.message) for w in rec
-            if issubclass(w.category, RuntimeWarning)]
-    assert len(msgs) == 1 and "uses_ssm" in msgs[0]
-    ops._PAGED_FALLBACK_WARNED.discard("zoo-test-config")
 
 
 # --------------------------------------------------------------------------

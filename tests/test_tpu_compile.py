@@ -1,0 +1,138 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e.
+
+Nothing runs: each test lowers and compiles for a chip that is described,
+not attached, which is what the chip's compiler would refuse (tiling,
+VMEM, memory) without spending chip time.  The topology is described in a
+module fixture, never at import, so every test worker collects the same
+tests and only the worker given this file loads the TPU library.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_arch
+from repro.kernels import decode_attention as da
+from repro.kernels.ops import KernelPolicy
+from repro.models import transformer as tfm
+from repro.runtime.steps import StepConfig, make_paged_decode_loop
+
+HBM_BYTES = 16 * 10**9          # one v5e chip
+
+# SmolLM-135M serving shapes: 16 slots x 2048 tokens in pages of 16
+B, HQ, HKV, D, PS, NB, Q = 16, 9, 3, 64, 16, 128, 4
+N_PAGES = B * NB + B
+# DeepSeek-V2 latent widths at batch 1
+MLA_HQ, MLA_R, MLA_RKV, MLA_NB = 128, 576, 512, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _gqa_case(one_chip, name: str, n_splits: int):
+    S = lambda shape, dt=jnp.bfloat16: _shape(one_chip, shape, dt)
+    pool, bt = S((N_PAGES, PS, HKV, D)), S((B, NB), jnp.int32)
+    pos, ring = S((B,), jnp.int32), S((B, NB * PS, HKV, D))
+    qv, new = S((B, Q, HQ, D)), S((B, Q, HKV, D))
+    p0 = S((), jnp.int32)
+    if name == "paged_decode":
+        return (lambda q, k, v, t, p: da.paged_decode_attention_pallas(
+            q, k, v, t, p, n_splits=n_splits),
+            S((B, 1, HQ, D)), pool, pool, bt, pos)
+    if name == "paged_decode_int8":
+        i8, sc = S((N_PAGES, PS, HKV, D), jnp.int8), \
+            S((N_PAGES, PS, HKV, 1), jnp.float32)
+        return (lambda q, k, v, ks, vs, t, p: da.paged_decode_attention_pallas(
+            q, k, v, t, p, n_splits=n_splits, k_scale=ks, v_scale=vs),
+            S((B, 1, HQ, D)), i8, i8, sc, sc, bt, pos)
+    if name == "paged_verify":
+        return (lambda q, k, v, kn, vn, t, p: da.paged_verify_attention_pallas(
+            q, k, v, kn, vn, t, p, n_splits=n_splits),
+            qv, pool, pool, new, new, bt, pos)
+    if name == "ring_decode":
+        return (lambda q, k, v, p: da.decode_attention_pallas(
+            q, k, v, p, n_splits=n_splits),
+            S((B, 1, HQ, D)), ring, ring, p0)
+    if name == "ring_verify":
+        return (lambda q, k, v, kn, vn, p: da.verify_attention_pallas(
+            q, k, v, kn, vn, p, n_splits=n_splits),
+            qv, ring, ring, new, new, p0)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("n_splits", [1, 2])
+@pytest.mark.parametrize("name", ["paged_decode", "paged_decode_int8",
+                                  "paged_verify", "ring_decode",
+                                  "ring_verify"])
+def test_gqa_sweep_compiles(one_chip, name, n_splits):
+    fn, *args = _gqa_case(one_chip, name, n_splits)
+    assert "tpu_custom_call" in _compile(fn, *args).as_text()
+
+
+@pytest.mark.parametrize("n_splits", [1, 4])
+def test_mla_paged_decode_compiles(one_chip, n_splits):
+    S = lambda shape, dt=jnp.bfloat16: _shape(one_chip, shape, dt)
+    compiled = _compile(
+        lambda q, lat, t, p: da.mla_paged_decode_attention_pallas(
+            q, lat, t, p, r_kv=MLA_RKV, n_splits=n_splits, scale=192 ** -0.5),
+        S((1, 1, MLA_HQ, MLA_R)), S((MLA_NB + 8, PS, MLA_R)),
+        S((1, MLA_NB), jnp.int32), S((1,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_merge_kernel_compiles(one_chip):
+    compiled = _compile(
+        lambda p, l: da.merge_kv_splits_pallas(p, l, out_dtype=jnp.bfloat16),
+        _shape(one_chip, (B, HQ, 4, 1, D), jnp.float32),
+        _shape(one_chip, (B, HQ, 4, 1), jnp.float32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_smollm_paged_decode_loop_compiles_and_fits(one_chip):
+    """The engine's fused decode loop at SmolLM-135M's published widths,
+    16 slots x 2048 tokens, with the Pallas decode sweep."""
+    cfg = get_arch("smollm-135m").config
+    step_cfg = StepConfig(remat="none",
+                          kernel_policy=KernelPolicy(decode="pallas"))
+    place = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(
+        lambda: tfm.init_lm(jax.random.PRNGKey(0), cfg)[0]))
+    cache = place(jax.eval_shape(
+        lambda: tfm.init_paged_cache(cfg, B, N_PAGES, PS, NB)))
+    loop = jax.jit(make_paged_decode_loop(cfg, step_cfg, n_tokens=8),
+                   donate_argnums=(1,))
+    compiled = loop.lower(params, cache,
+                          _shape(one_chip, (B, 1), jnp.int32),
+                          _shape(one_chip, (B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, f"decode loop needs {total / 1e9:.2f} GB"
