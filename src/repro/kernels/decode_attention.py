@@ -191,6 +191,7 @@ def merge_kv_splits_pallas(partial: jax.Array, lse: jax.Array, *,
     N = pf.shape[0]
     out = pl.pallas_call(
         _lse_merge_kernel,
+        name="split_kv_merge",
         grid=(N,),
         in_specs=[pl.BlockSpec((1, S, R, Dv), lambda n: (n, 0, 0, 0)),
                   pl.BlockSpec((1, S, R), lambda n: (n, 0, 0))],
@@ -379,6 +380,7 @@ def decode_attention_pallas_partials(
     )
     partial, lse = pl.pallas_call(
         kernel,
+        name="ring_decode_partials",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hq, n_splits, 1, Dv), jnp.float32),
                    jax.ShapeDtypeStruct((B, Hq, n_splits, 1, 1), jnp.float32)],
@@ -475,6 +477,7 @@ def decode_attention_pallas(
     )
     out = pl.pallas_call(
         kernel,
+        name="ring_decode_sweep",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, 1, Dv), q.dtype),
         interpret=interpret,
@@ -678,6 +681,7 @@ def verify_attention_pallas_partials(
     )
     partial, lse = pl.pallas_call(
         kernel,
+        name="ring_verify_partials",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hq, n_splits, Q, Dv), jnp.float32),
                    jax.ShapeDtypeStruct((B, Hq, n_splits, 1, Q), jnp.float32)],
@@ -778,6 +782,7 @@ def verify_attention_pallas(
     )
     out = pl.pallas_call(
         kernel,
+        name="ring_verify_sweep",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Q, Dv), q.dtype),
         interpret=interpret,
@@ -967,6 +972,7 @@ def paged_verify_attention_pallas_partials(
     )
     partial, lse = pl.pallas_call(
         kernel,
+        name="paged_verify_partials",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hq, n_splits, Q, Dv), jnp.float32),
                    jax.ShapeDtypeStruct((B, Hq, n_splits, 1, Q), jnp.float32)],
@@ -1065,6 +1071,7 @@ def paged_verify_attention_pallas(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_verify_sweep",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Q, Dv), q.dtype),
         interpret=interpret,
@@ -1230,6 +1237,7 @@ def paged_decode_attention_pallas_partials(
     )
     partial, lse = pl.pallas_call(
         kernel,
+        name="paged_decode_partials",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hq, n_splits, 1, Dv), jnp.float32),
                    jax.ShapeDtypeStruct((B, Hq, n_splits, 1, 1), jnp.float32)],
@@ -1322,6 +1330,7 @@ def paged_decode_attention_pallas(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_sweep",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, 1, Dv), q.dtype),
         interpret=interpret,
@@ -1478,6 +1487,7 @@ def mla_paged_decode_attention_pallas_partials(
     )
     partial, lse = pl.pallas_call(
         kernel,
+        name="mla_paged_decode_partials",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, n_splits, Hq, r_kv), jnp.float32),
@@ -1544,6 +1554,7 @@ def mla_paged_decode_attention_pallas(
     )
     out = pl.pallas_call(
         kernel,
+        name="mla_paged_decode_sweep",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, r_kv), q_lat.dtype),
         interpret=interpret,

@@ -134,6 +134,7 @@ def flash_attention(
 
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B, Hq, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D),

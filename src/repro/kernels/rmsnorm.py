@@ -39,6 +39,7 @@ def rmsnorm_pallas(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
     kernel = functools.partial(_rms_kernel, eps=eps, gemma_style=gemma_style)
     out = pl.pallas_call(
         kernel,
+        name="rmsnorm",
         grid=(rows // block_rows,),
         in_specs=[pl.BlockSpec((block_rows, d), lambda r: (r, 0)),
                   pl.BlockSpec((d,), lambda r: (0,))],

@@ -108,6 +108,7 @@ def ssd_pallas(
     kernel = functools.partial(_ssd_kernel, chunk=chunk, has_D=D is not None)
     y = pl.pallas_call(
         kernel,
+        name="ssd_scan",
         grid=(Bb, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, P), lambda b, h, c: (b, h, c, 0, 0)),

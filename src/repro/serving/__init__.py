@@ -15,6 +15,8 @@ Layering::
                  J/token accounting; chaos injection, snapshot/restore
                  (EngineCrash recovery), graceful degradation under
                  emergency caps
+    spans        SpanRecorder — the engine's phases and counts on
+                 perf_counter, annotated on jax.profiler traces
 
 See docs/serving_engine.md, docs/prefix_cache.md and
 docs/fault_tolerance.md.
@@ -25,11 +27,12 @@ from repro.serving.engine import (ChunkStats, EnergyAwareAdmission,
 from repro.serving.paged_kv import CopySpec, PagedKVCache
 from repro.serving.request import Request, RequestResult
 from repro.serving.scheduler import RequestQueue, Scheduler
+from repro.serving.spans import Span, SpanRecorder
 from repro.serving.traffic import batch_trace, poisson_trace
 
 __all__ = [
     "ChunkStats", "CopySpec", "EnergyAwareAdmission", "EngineConfig",
     "EngineCrash", "EngineReport", "PagedKVCache", "Request",
-    "RequestQueue", "RequestResult", "Scheduler", "ServeEngine",
-    "batch_trace", "poisson_trace",
+    "RequestQueue", "RequestResult", "Scheduler", "ServeEngine", "Span",
+    "SpanRecorder", "batch_trace", "poisson_trace",
 ]

@@ -26,7 +26,11 @@ passes an ``on_chunk`` hook that receives per-chunk :class:`ChunkStats`
 (measured wall time, occupancy, useful-vs-computed tokens) and returns the
 chunk's energy in joules (or ``None``).  Energy is attributed to requests
 in proportion to their *kept* tokens, so J/token charges only occupied
-slots — utilisation-honest under partial occupancy.
+slots — utilisation-honest under partial occupancy.  A
+:class:`~repro.serving.spans.SpanRecorder` passed as ``spans`` records the
+engine's phases (poll, join, page growth, each chunk and its steps) with
+their counts, and annotates them on a ``jax.profiler`` trace; see
+docs/serving_engine.md, "Spans".
 
 KV storage is tiered (``EngineConfig.kv_dtype`` / ``host_tier``): int8
 pages with per-row fp32 scales quarter the device footprint of a page
@@ -45,6 +49,7 @@ more host mirror, seeded at prefill-on-join.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import time
@@ -68,6 +73,9 @@ from repro.runtime.steps import (StepConfig, make_paged_decode_loop,
 from repro.serving.paged_kv import PagedKVCache
 from repro.serving.request import Request, RequestResult
 from repro.serving.scheduler import RequestQueue, Scheduler
+from repro.serving.spans import SpanRecorder
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 class EngineCrash(RuntimeError):
@@ -164,7 +172,6 @@ class EngineReport:
     results: list[RequestResult]
     n_chunks: int = 0
     decode_wall_s: float = 0.0
-    prefill_wall_s: float = 0.0
     tokens_kept: int = 0
     tokens_computed: int = 0
     energy_j: float = 0.0
@@ -275,7 +282,8 @@ class ServeEngine:
                  injector: FaultInjector | None = None,
                  on_heartbeat: Callable[[int, float], None] | None = None,
                  on_fault: Callable[[object], None] | None = None,
-                 snapshot_dir: str | None = None, snapshot_every: int = 0):
+                 snapshot_dir: str | None = None, snapshot_every: int = 0,
+                 spans: SpanRecorder | None = None):
         self.cfg = cfg
         self.ecfg = engine_cfg
         self.params = params
@@ -314,6 +322,8 @@ class ServeEngine:
         # serving supervisor; suppressed while a "stall" fault is active
         self.on_heartbeat = on_heartbeat
         self.on_fault = on_fault
+        # None: no spans recorded, no profiler annotations
+        self.spans = spans
         self.snapshot_every = int(snapshot_every)
         self._ckpt = CheckpointManager(snapshot_dir, keep=2) \
             if snapshot_dir else None
@@ -391,6 +401,10 @@ class ServeEngine:
         self._stall_until = -1             # heartbeat suppression window end
         self._eff_chunk = engine_cfg.decode_chunk
 
+    def _span(self, name: str, rid: int | None = None):
+        """A recorded span around a block, or nothing without a recorder."""
+        return _NO_SPAN if self.spans is None else self.spans.span(name, rid)
+
     # -- compiled pieces (AOT so compile time never lands in measured walls) -
     def _chunk_loop(self, chunk: int, spec: bool, *args):
         key = (chunk, spec)
@@ -459,7 +473,7 @@ class ServeEngine:
         if self._tier_restore is None:
             tsubs = self._table_subs
 
-            def restore(cache, page, blob):
+            def tier_restore(cache, page, blob):
                 def put(name, c):
                     return {key: pool.at[:, page].set(
                         blob[f"{name}/{key}"].astype(pool.dtype))
@@ -472,7 +486,7 @@ class ServeEngine:
                     out["dense"] = put("dense", cache["dense"])
                 return out
 
-            self._tier_restore = jax.jit(restore, donate_argnums=(0,))
+            self._tier_restore = jax.jit(tier_restore, donate_argnums=(0,))
         self.cache = self._tier_restore(
             self.cache, jnp.asarray(page, jnp.int32),
             {k: jnp.asarray(v) for k, v in blob.items()})
@@ -619,7 +633,7 @@ class ServeEngine:
                     new[key] = flat.reshape(pool.shape)
                 return new
 
-            def copy(cache, src, dst, n_rows):
+            def page_copy(cache, src, dst, n_rows):
                 units = {name: (copy_group(c, src, dst, n_rows)
                                 if name in tsubs else c)
                          for name, c in cache["units"].items()}
@@ -629,7 +643,7 @@ class ServeEngine:
                                               n_rows)
                 return out
 
-            self._copy = jax.jit(copy, donate_argnums=(0,))
+            self._copy = jax.jit(page_copy, donate_argnums=(0,))
         return self._copy
 
     def _suffix_step(self, args):
@@ -666,6 +680,10 @@ class ServeEngine:
         return logits[slot, r - 1]
 
     # -- join ----------------------------------------------------------------
+    def _sweeps(self, L: int, m: int) -> int:
+        """Chunked-prefill sweeps a join restoring ``m`` of ``L`` runs."""
+        return -(-(L - m) // self.ecfg.prefill_chunk) if m > 0 else 0
+
     def _sample_first(self, logits_row, rid: int):
         """Sample the prefill's token (greedy or temperature) — position
         prompt_len - 1 of the padded prefill logits."""
@@ -686,15 +704,19 @@ class ServeEngine:
             # CoW: the match ended inside a shared page — duplicate the
             # matched rows into the slot's private page before the suffix
             # prefill writes right behind them
-            self.cache = self._page_copy()(
-                self.cache, jnp.asarray(copy.src_page, jnp.int32),
-                jnp.asarray(copy.dst_page, jnp.int32),
-                jnp.asarray(copy.n_rows, jnp.int32))
-            self.kv.copy_done(copy.src_page)
+            with self._span("page_copy"):
+                self.cache = self._page_copy()(
+                    self.cache, jnp.asarray(copy.src_page, jnp.int32),
+                    jnp.asarray(copy.dst_page, jnp.int32),
+                    jnp.asarray(copy.n_rows, jnp.int32))
+                self.kv.copy_done(copy.src_page)
         if m > 0:
             # prefill ONLY the uncached suffix, through the paged verify
             # sweep (chunked, fixed-shape, in-place commit)
-            logits_row = self._prefill_suffix(slot, req, m)
+            with self._span("suffix_prefill") as s:
+                logits_row = self._prefill_suffix(slot, req, m)
+            if s is not None:
+                s.counts["sweeps"] = self._sweeps(L, m)
         else:
             # cold prompt: classic bucketed prefill + page inject.  SSM
             # families prefill at the EXACT prompt length: attention caches
@@ -706,18 +728,22 @@ class ServeEngine:
             pad_shape = (1, bucket - L) + req.prompt.shape[1:]
             inputs = np.concatenate(
                 [req.prompt[None], np.zeros(pad_shape, np.int32)], axis=1)
-            logits, pcache = self._prefill(bucket)(self.params,
-                                                   jnp.asarray(inputs))
-            logits_row = logits[0, L - 1]
-            rows = {k: jnp.asarray(v) for k, v in
-                    self._inject_rows_tree(slot, bucket, L).items()}
-            self.cache = self._inject(bucket)(self.cache, pcache, rows,
-                                              jnp.asarray(slot, jnp.int32))
-        first = self._sample_first(logits_row, req.rid)
+            with self._span("prefill"):
+                logits, pcache = self._prefill(bucket)(self.params,
+                                                       jnp.asarray(inputs))
+                logits_row = logits[0, L - 1]
+            with self._span("inject"):
+                rows = {k: jnp.asarray(v) for k, v in
+                        self._inject_rows_tree(slot, bucket, L).items()}
+                self.cache = self._inject(bucket)(
+                    self.cache, pcache, rows, jnp.asarray(slot, jnp.int32))
+        with self._span("sample_first"):     # the join's device sync
+            first = self._sample_first(logits_row, req.rid)
         self._pos[slot] = L
         if self._use_prefix:
             # index the prompt's (now fully written) pages for future joins
-            self.kv.register_prefix(slot, np.asarray(req.prompt))
+            with self._span("register_prefix"):
+                self.kv.register_prefix(slot, np.asarray(req.prompt))
         if self._drafter is not None:
             self._drafter.seed_request(self._dstate, slot, req.prompt, first)
         state = self.scheduler.slots[slot]
@@ -774,11 +800,12 @@ class ServeEngine:
         res.n_preemptions += 1
         self._report.n_preemptions += 1
 
-    def _grow_pages(self, t0: float, need: int | None = None) -> None:
+    def _grow_pages(self, t0: float, need: int | None = None) -> int:
         """Lazy-allocation mode: before a chunk, grow every active slot's
         pages to cover the chunk's writes, preempting the lowest-priority
         slot when the pool runs dry (``Scheduler.victim``: lowest
-        priority, then most recently admitted)."""
+        priority, then most recently admitted).  Returns the pages grown
+        on slots that stay."""
         ecfg = self.ecfg
         if need is None:
             need = ecfg.decode_chunk * (ecfg.spec_k + 1)
@@ -786,6 +813,7 @@ class ServeEngine:
         order = sorted(self.scheduler.active_slots(),
                        key=lambda s: (-slots[s].request.priority,
                                       slots[s].seq))
+        grown = 0
         for slot in order:
             if slots[slot] is None:
                 continue                   # preempted earlier this pass
@@ -795,6 +823,7 @@ class ServeEngine:
             req = slots[slot].request
             ctx = req.prompt_len + req.max_new_tokens - 1
             target = min(int(self._pos[slot]) + need, ctx, self.kv.max_len)
+            had = len(self.kv.allocated[slot])
             while not self.kv.ensure(slot, target):
                 victim = self.scheduler.victim()
                 if victim == slot:
@@ -806,6 +835,9 @@ class ServeEngine:
                     self._preempt(slot, t0)
                     break
                 self._preempt(victim, t0)
+            if slots[slot] is not None:
+                grown += len(self.kv.allocated[slot]) - had
+        return grown
 
     # -- harvest -------------------------------------------------------------
     def _harvest(self, toks: np.ndarray, t0: float) -> dict[int, int]:
@@ -963,7 +995,8 @@ class ServeEngine:
     def save_snapshot(self) -> None:
         if self._ckpt is None:
             raise ValueError("engine was built without snapshot_dir")
-        self._ckpt.save(self.snapshot(), self._now)
+        with self._span("snapshot"):
+            self._ckpt.save(self.snapshot(), self._now)
 
     @classmethod
     def restore(cls, cfg, engine_cfg: EngineConfig, params, snapshot_dir,
@@ -1085,11 +1118,20 @@ class ServeEngine:
                 self._degrade_level = 0      # window cleared: full service
                 self._cap_frac = 1.0
             degraded = self._degrade_level
-            t_p = time.perf_counter()
             if not degraded:                 # degraded: admission paused
-                for slot, req, m, copy in self.scheduler.poll(queue,
-                                                              self._now):
-                    self._join(slot, req, m, copy, t0)
+                with self._span("poll") as s:
+                    joins = self.scheduler.poll(queue, self._now)
+                if s is not None:
+                    s.counts.update(admitted=len(joins),
+                                    queue_depth=len(queue))
+                for slot, req, m, copy in joins:
+                    with self._span("join", req.rid) as s:
+                        self._join(slot, req, m, copy, t0)
+                    if s is not None:
+                        s.counts.update(prompt_tokens=req.prompt_len,
+                                        restored=m,
+                                        sweeps=self._sweeps(req.prompt_len,
+                                                            m))
             # emergency cap: halve the decode chunk, drop speculation — the
             # chunk's compute shrinks instead of violating the cap
             eff_chunk = ecfg.decode_chunk if degraded < 2 \
@@ -1100,12 +1142,16 @@ class ServeEngine:
             if ecfg.preempt:
                 # grows/preempts but always leaves >= 1 slot active (the
                 # last survivor raises rather than self-preempting)
-                self._grow_pages(t0, eff_chunk * (eff_k + 1))
+                n_pre = report.n_preemptions
+                with self._span("grow_pages") as s:
+                    grown = self._grow_pages(t0, eff_chunk * (eff_k + 1))
+                if s is not None:
+                    s.counts.update(grown=grown,
+                                    preempted=report.n_preemptions - n_pre)
             # joins may have paged prefixes back in, growth may have paged
             # cold pages out — fold the modelled transfer energy in now so
             # every ChunkStats-adjacent ledger read sees it
             self._sync_transfer()
-            report.prefill_wall_s += time.perf_counter() - t_p
 
             if self.scheduler.n_active == 0:
                 if degraded:
@@ -1130,6 +1176,10 @@ class ServeEngine:
                 self._now = nxt                   # idle: jump to next arrival
                 continue
 
+            sp = self.spans
+            if sp is not None:
+                sp.open("chunk")
+                sp.open("upload")
             active = np.zeros((ecfg.n_slots,), np.int32)
             for slot in self.scheduler.active_slots():
                 active[slot] = 1
@@ -1148,17 +1198,27 @@ class ServeEngine:
                 args.append(jax.random.fold_in(self._sample_key,
                                                self._chunk_idx << 1))
             loop = self._chunk_loop(eff_chunk, spec, *args)
+            # wall_s is exactly the dispatch and device_wait spans: the
+            # three clock readings are shared
             t_c = time.perf_counter()
+            if sp is not None:
+                sp.switch("dispatch", t_c)
             if spec:
                 toks, counts, self.cache, dstate = loop(*args)
-                toks = np.asarray(jax.block_until_ready(toks))
+            else:
+                toks, self.cache = loop(*args)
+            t_d = time.perf_counter()
+            if sp is not None:
+                sp.switch("device_wait", t_d)
+            toks = np.asarray(jax.block_until_ready(toks))
+            if spec:
                 counts = np.asarray(counts)
                 # np.array (not asarray): seed_row mutates this mirror on join
                 self._dstate = {k: np.array(v) for k, v in dstate.items()}
-            else:
-                toks, self.cache = loop(*args)
-                toks = np.asarray(jax.block_until_ready(toks))
-            wall = time.perf_counter() - t_c
+            t_w = time.perf_counter()
+            wall = t_w - t_c
+            if sp is not None:
+                sp.switch("harvest", t_w)
 
             n_active = int(active.sum())
             if spec:
@@ -1179,6 +1239,8 @@ class ServeEngine:
             self._chunk_idx += 1
             if degraded:
                 report.degraded_steps += eff_chunk
+            if sp is not None:
+                sp.switch("on_chunk")
 
             stats = ChunkStats(step=self._chunk_idx, wall_s=wall,
                                n_slots=ecfg.n_slots, n_active=n_active,
@@ -1188,6 +1250,10 @@ class ServeEngine:
                                clock_step=self._now,
                                degrade_level=degraded)
             energy = self.on_chunk(stats) if self.on_chunk is not None else None
+            if sp is not None:
+                sp.close()
+                sp.close(n_active=n_active, tokens_kept=kept,
+                         finished=n_active - self.scheduler.n_active)
             report.n_chunks += 1
             report.decode_wall_s += wall
             report.tokens_kept += kept

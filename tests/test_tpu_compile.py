@@ -19,7 +19,8 @@ from repro.configs import get_arch
 from repro.kernels import decode_attention as da
 from repro.kernels.ops import KernelPolicy
 from repro.models import transformer as tfm
-from repro.runtime.steps import StepConfig, make_paged_decode_loop
+from repro.runtime.steps import (StepConfig, make_paged_decode_loop,
+                                 make_prefill_suffix_step)
 
 HBM_BYTES = 16 * 10**9          # one v5e chip
 
@@ -52,6 +53,14 @@ def _shape(one_chip, shape, dtype):
 
 def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
+
+
+def _kernels(compiled) -> set[str]:
+    """The names the compiled program gives its Pallas kernels (the
+    instruction names a device trace shows)."""
+    return {line.split("=", 1)[0].strip().lstrip("%").split(".", 1)[0]
+            for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
 
 
 def _gqa_case(one_chip, name: str, n_splits: int):
@@ -91,7 +100,10 @@ def _gqa_case(one_chip, name: str, n_splits: int):
                                   "ring_verify"])
 def test_gqa_sweep_compiles(one_chip, name, n_splits):
     fn, *args = _gqa_case(one_chip, name, n_splits)
-    assert "tpu_custom_call" in _compile(fn, *args).as_text()
+    family = name.removesuffix("_int8")
+    assert _kernels(_compile(fn, *args)) == (
+        {f"{family}_sweep"} if n_splits == 1
+        else {f"{family}_partials", "split_kv_merge"})
 
 
 @pytest.mark.parametrize("n_splits", [1, 4])
@@ -102,7 +114,9 @@ def test_mla_paged_decode_compiles(one_chip, n_splits):
             q, lat, t, p, r_kv=MLA_RKV, n_splits=n_splits, scale=192 ** -0.5),
         S((1, 1, MLA_HQ, MLA_R)), S((MLA_NB + 8, PS, MLA_R)),
         S((1, MLA_NB), jnp.int32), S((1,), jnp.int32))
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == (
+        {"mla_paged_decode_sweep"} if n_splits == 1
+        else {"mla_paged_decode_partials", "split_kv_merge"})
 
 
 def test_merge_kernel_compiles(one_chip):
@@ -110,7 +124,7 @@ def test_merge_kernel_compiles(one_chip):
         lambda p, l: da.merge_kv_splits_pallas(p, l, out_dtype=jnp.bfloat16),
         _shape(one_chip, (B, HQ, 4, 1, D), jnp.float32),
         _shape(one_chip, (B, HQ, 4, 1), jnp.float32))
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == {"split_kv_merge"}
 
 
 def test_smollm_paged_decode_loop_compiles_and_fits(one_chip):
@@ -131,8 +145,32 @@ def test_smollm_paged_decode_loop_compiles_and_fits(one_chip):
     compiled = loop.lower(params, cache,
                           _shape(one_chip, (B, 1), jnp.int32),
                           _shape(one_chip, (B,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text().startswith("HloModule jit_decode_loop")
+    assert _kernels(compiled) == {"paged_decode_sweep"}
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES, f"decode loop needs {total / 1e9:.2f} GB"
+
+
+def test_smollm_suffix_step_names_its_kernel(one_chip):
+    """The engine's chunked suffix-prefill sweep at SmolLM-135M's widths:
+    a program named after its function, with the named verify kernel the
+    benchmark's suffix-sweep reader counts."""
+    cfg = get_arch("smollm-135m").config
+    step_cfg = StepConfig(remat="none",
+                          kernel_policy=KernelPolicy(decode="pallas"))
+    place = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(
+        lambda: tfm.init_lm(jax.random.PRNGKey(0), cfg)[0]))
+    cache = place(jax.eval_shape(
+        lambda: tfm.init_paged_cache(cfg, B, N_PAGES, PS, NB)))
+    step = jax.jit(make_prefill_suffix_step(cfg, step_cfg),
+                   donate_argnums=(1,))
+    compiled = step.lower(params, cache,
+                          _shape(one_chip, (B, 16), jnp.int32),
+                          _shape(one_chip, (B,), jnp.int32)).compile()
+    assert compiled.as_text().startswith("HloModule jit_suffix_step")
+    assert _kernels(compiled) == {"paged_verify_sweep"}
