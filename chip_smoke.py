@@ -233,7 +233,9 @@ def gqa_kernel_phase(*, batch: int, q_heads: int, kv_heads: int,
         qv, kp, vp, kn, vn, bt, pos)
     with jax.default_matmul_precision("highest"):
         part_w, lse_w = ref.paged_decode_attention_split_ref(
-            q, kp, vp, bt, pos, n_splits=n_splits)
+            q, kp, vp, bt, pos, n_splits=n_splits,
+            block_pages=da.pages_per_block(page_size, kv_heads, head_dim,
+                                           kp.dtype.itemsize, nb))
         dec_w = ref.paged_decode_attention_ref(q, kp, vp, bt, pos)
         ver_w = ref.paged_verify_attention_ref(qv, kp, vp, kn, vn, bt, pos)
     errs = {"decode_partial_err": _max_err(part, part_w),

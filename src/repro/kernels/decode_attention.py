@@ -27,6 +27,11 @@ fp32 scratch:
     cache stays in storage dtype — the whole-cache fp32 cast this kernel
     replaces tripled decode HBM traffic.
 
+The paged single-token sweep (``paged_decode_attention_pallas``) tiles
+differently: grid ``(batch, page_blocks)``, each step a block of whole
+pages — every kv head — read from the pool as stored and folded into every
+query head of its slot (section "Paged single-token decode" below).
+
 Both layouts also carry a *multi-query verify* variant for speculative
 decoding (``verify_attention_pallas`` / ``paged_verify_attention_pallas``):
 ``q_len = K+1`` query rows share ONE cache sweep, the causal offset masks
@@ -1079,91 +1084,242 @@ def paged_verify_attention_pallas(
     return out.transpose(0, 2, 1, 3)             # (B, Q, Hq, Dv)
 
 
-def _paged_decode_kernel(bt_ref, pos_ref, *refs, scale: float, window: int,
-                         logit_cap: float, page_size: int, n_blocks: int,
-                         quantized: bool = False):
-    if quantized:
-        (q_ref, k_ref, ks_ref, v_ref, vs_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
-        ks_ref = vs_ref = None
-    ib, ij = pl.program_id(0), pl.program_id(2)
+# --------------------------------------------------------------------------
+# Paged single-token decode — one grid step per slot x block of pages
+# --------------------------------------------------------------------------
+#
+# The pool is read as it is stored, ``(P, ps, Hkv, D)``: a grid step covers
+# ``ppc`` consecutive table entries of one slot, each a whole page (every kv
+# head) fetched by its own BlockSpec operand through the block table, and
+# folds them into every query head of that slot.  ``ppc`` follows from the
+# page's VMEM footprint (``pages_per_block``).  The index maps clamp a
+# slot's blocks to those holding keys it may attend, so grid steps outside
+# them repeat the previous step's pages and issue no DMA.
 
-    @pl.when(ij == 0)
+_KV_TILE_BYTES = 1 << 20        # VMEM for one pool's block of pages
+_MAX_BLOCK_TOKENS = 512
+
+
+def _vmem_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a ``(rows, cols)`` slab: rows padded to the dtype's
+    sublane tile (8 rows of 32 bits, 16 of 16, 32 of 8), columns to 128
+    lanes."""
+    sub = 32 // itemsize
+    return -(-rows // sub) * sub * (-(-cols // 128) * 128) * itemsize
+
+
+def pages_per_block(page_size: int, n_kv_heads: int, head_dim: int,
+                    itemsize: int, n_pages: int, *,
+                    quantized: bool = False) -> int:
+    """Pages a paged decode grid step covers: as many as keep one pool's
+    block of pages (all kv heads, int8 scales included) within
+    ``_KV_TILE_BYTES`` of VMEM and within ``_MAX_BLOCK_TOKENS`` tokens,
+    never more than the table holds, at least one."""
+    token = _vmem_bytes(n_kv_heads, head_dim, itemsize)
+    if quantized:
+        token += _vmem_bytes(n_kv_heads, 1, 4)
+    ppc = min(_KV_TILE_BYTES // (page_size * token),
+              _MAX_BLOCK_TOKENS // page_size, n_pages)
+    return max(1, ppc)
+
+
+def paged_decode_grid(batch: int, n_pages: int, ppc: int,
+                      n_splits: int | None = None) -> tuple[int, ...]:
+    """Grid of the paged decode sweep: ``(B, blocks)`` single-stage
+    (``n_splits is None``), or ``(B, S, blocks per split)`` for the
+    partials twin, the split axis cutting page blocks (``S`` clamped to the
+    block count)."""
+    n_blk = -(-n_pages // ppc)
+    if n_splits is None:
+        return (batch, n_blk)
+    s, bps = _split_blocks(n_blk, n_splits)
+    return (batch, s, bps)
+
+
+def _block_range(pos, *, window: int, block_tokens: int):
+    """First and last page block holding a key the query at ``pos`` may
+    attend (the window's first key .. ``pos``); at least one block."""
+    lo = jnp.maximum(pos - window + 1, 0) // block_tokens if window > 0 else 0
+    return lo, jnp.maximum(pos // block_tokens, lo)
+
+
+def _paged_decode_kernel(bt_ref, pos_ref, q_ref, *refs, scale: float,
+                         window: int, logit_cap: float, page_size: int,
+                         ppc: int, bps: int, quantized: bool, partials: bool):
+    """Paged decode sweep over a slot's page blocks, every head at once.
+
+    Single-stage grid ``(B, blocks)``; partials grid ``(B, S, bps)`` where
+    split ``s`` owns blocks ``[s*bps, (s+1)*bps)``.  Block ``g`` of slot
+    ``b`` holds positions ``[g*T, (g+1)*T)``, ``T = ppc*ps``; its ``ppc``
+    pages arrive as ``ppc`` refs per pool, ``(ps, Hkv, D)`` each.  The
+    block is stacked to ``(T, Hkv, D)``, turned kv-head-major in VMEM, and
+    folded into the slot's ``(Hkv, G)`` query rows by one batched matmul
+    per side — q (``(Hkv, G, D)``) and the running m/l/acc stay resident
+    across the slot's blocks.  Blocks outside ``_block_range`` skip their
+    compute (and, by the index maps, their DMA)."""
+    n_pools = 4 if quantized else 2
+    pools = [refs[i * ppc:(i + 1) * ppc] for i in range(n_pools)]
+    outs, (m_ref, l_ref, acc_ref) = refs[n_pools * ppc:-3], refs[-3:]
+    if quantized:
+        k_refs, ks_refs, v_refs, vs_refs = pools
+    else:
+        (k_refs, v_refs), ks_refs, vs_refs = pools, None, None
+    T = ppc * page_size
+    Hkv, G = q_ref.shape[1:3]
+    b = pl.program_id(0)
+    if partials:
+        j = pl.program_id(2)
+        g, last = pl.program_id(1) * bps + j, bps - 1
+    else:
+        j = g = pl.program_id(1)
+        last = pl.num_programs(1) - 1
+
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # paged layout is *linear*: logical block j of request b holds absolute
-    # positions [j*ps, (j+1)*ps) — no ring arithmetic, the block table alone
-    # says where those positions live in the pool
-    pos = pos_ref[ib]
-    k_pos = ij * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)
+    pos = pos_ref[b]
+    lo, hi = _block_range(pos, window=window, block_tokens=T)
+    k_pos = g * T + jax.lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
     valid = k_pos <= pos
     if window > 0:
         valid = jnp.logical_and(valid, k_pos > pos - window)
 
-    # blocks wholly beyond the request's length (or outside the window) are
-    # predicated off — under partial occupancy most of the grid is this case
-    @pl.when(jnp.any(valid))
+    @pl.when((g >= lo) & (g <= hi) & jnp.any(valid))
     def _compute():
-        _online_softmax_update(
-            q_ref[0, 0].astype(jnp.float32),                 # (1, D)
-            _load_kv(k_ref, ks_ref),                         # (ps, D)
-            _load_kv(v_ref, vs_ref),                         # (ps, Dv)
-            valid, m_ref, l_ref, acc_ref, scale=scale, logit_cap=logit_cap)
+        # MXU operands in the pool's float dtype (fp32 after int8 dequant),
+        # fp32 accumulation and softmax.  bf16 products are exact in fp32,
+        # so a bf16 pool's matmuls run at the default precision whatever
+        # precision the caller asks of fp32 matmuls, and the fp32 softmax
+        # weights enter p @ v as two bf16 terms (high and low part)
+        dt = k_refs[0].dtype if not quantized else jnp.float32
+        prec = jax.lax.Precision.DEFAULT if dt == jnp.bfloat16 else None
 
-    @pl.when(ij == n_blocks - 1)
+        def block(refs, scale_refs):
+            x = jnp.concatenate([r[0] for r in refs], axis=0)   # (T, Hkv, D)
+            if scale_refs is not None:
+                x = x.astype(jnp.float32) * jnp.concatenate(
+                    [r[0] for r in scale_refs], axis=0)
+            return jnp.swapaxes(x.astype(dt), 0, 1)             # (Hkv, T, D)
+
+        k, v = block(k_refs, ks_refs), block(v_refs, vs_refs)
+        pv = lambda w: jnp.einsum("hgt,htd->hgd", w.astype(dt), v,
+                                  precision=prec,
+                                  preferred_element_type=jnp.float32)
+        s = jnp.einsum("hgd,htd->hgt", q_ref[0].astype(dt), k,
+                       precision=prec,
+                       preferred_element_type=jnp.float32) * scale
+        if logit_cap > 0.0:
+            s = logit_cap * jnp.tanh(s / logit_cap)
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[...]                                     # (Hkv, G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=2, keepdims=True)
+        acc = acc_ref[...] * corr + pv(p)
+        if dt == jnp.bfloat16:
+            acc += pv(p - p.astype(dt).astype(jnp.float32))
+        acc_ref[...] = acc
+        m_ref[...] = m_new
+
+    @pl.when(j == last)
     def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        # kv-head-major rows are q heads h*G .. h*G+G-1
+        for h in range(Hkv):
+            rows = slice(h * G, (h + 1) * G)
+            l = l_ref[h]                                        # (G, 1)
+            out = acc_ref[h] / jnp.maximum(l, 1e-30)            # (G, Dv)
+            if partials:
+                # split partial and its lse (pinned to NEG_INF where no key
+                # was valid, so the merge weighs it to zero)
+                outs[0][0, 0, rows] = out
+                outs[1][0, 0, rows] = jnp.where(
+                    l > 0.0, m_ref[h] + jnp.log(jnp.maximum(l, 1e-30)),
+                    NEG_INF)
+            else:
+                outs[0][0, rows] = out[:, None, :].astype(outs[0].dtype)
 
 
-def _paged_decode_partials_kernel(bt_ref, pos_ref, *refs,
-                                  scale: float, window: int, logit_cap: float,
-                                  page_size: int, n_blocks: int, ppb: int,
-                                  quantized: bool = False):
-    """Stage 1 of the two-stage paged decode sweep: identical masks to
-    ``_paged_decode_kernel``, but each split flushes normalized partials +
-    LSE instead of chaining scratch across every page."""
-    if quantized:
-        (q_ref, k_ref, ks_ref, v_ref, vs_ref,
-         part_ref, lse_ref, m_ref, l_ref, acc_ref) = refs
+def _paged_decode_call(q, k_pages, v_pages, block_tables, pos, *, n_splits,
+                       window, logit_cap, scale, interpret, k_scale, v_scale):
+    """The paged decode ``pallas_call``: single-stage (``n_splits`` None,
+    output ``(B, Hq, 1, Dv)`` in ``q``'s dtype) or stage 1 of the two-stage
+    sweep (fp32 ``partial (B, S, Hq, Dv)`` and ``lse (B, S, Hq, 1)``)."""
+    quantized = k_scale is not None
+    assert quantized == (v_scale is not None), \
+        "k_scale and v_scale must be given together"
+    B, _, Hq, D = q.shape
+    ps, Hkv = k_pages.shape[1:3]
+    Dv = v_pages.shape[-1]
+    nb = block_tables.shape[1]
+    G = Hq // Hkv
+    if scale is None:
+        scale = D ** -0.5
+    ppc = pages_per_block(ps, Hkv, max(D, Dv), k_pages.dtype.itemsize, nb,
+                          quantized=quantized)
+    grid = paged_decode_grid(B, nb, ppc, n_splits)
+    partials, bps, T = len(grid) == 3, grid[-1], ppc * ps
+
+    def page_spec(i, width):
+        """Page ``i`` of the grid step's block.  The block is clamped into
+        the slot's ``_block_range``; a page past ``pos`` repeats the page
+        this operand held one block earlier (or the slot's last page in its
+        first block), so steps outside the range, and the tail of the last
+        block, fetch nothing new."""
+        def index(b, *rest):
+            *ids, bt_ref, pos_ref = rest
+            g = ids[0] * bps + ids[1] if partials else ids[0]
+            p = pos_ref[b]
+            lo, hi = _block_range(p, window=window, block_tokens=T)
+            g = jnp.clip(g, lo, hi)
+            jp, last = g * ppc + i, p // ps
+            jp = jnp.where(jp <= last, jp,
+                           jnp.where(g > lo, jp - ppc, last))
+            return (bt_ref[b, jnp.clip(jp, 0, nb - 1)], 0, 0, 0)
+        return pl.BlockSpec((1, ps, Hkv, width), index)
+
+    pools = ([(k_pages, D), (k_scale, 1), (v_pages, Dv), (v_scale, 1)]
+             if quantized else [(k_pages, D), (v_pages, Dv)])
+    if partials:
+        S = grid[1]
+        at_split = lambda *shape: pl.BlockSpec(
+            (1, 1) + shape, lambda b, s, j, *_: (b, s) + (0,) * len(shape))
+        out_specs = [at_split(Hq, Dv), at_split(Hq, 1)]
+        out_shape = [jax.ShapeDtypeStruct((B, S, Hq, Dv), jnp.float32),
+                     jax.ShapeDtypeStruct((B, S, Hq, 1), jnp.float32)]
     else:
-        (q_ref, k_ref, v_ref,
-         part_ref, lse_ref, m_ref, l_ref, acc_ref) = refs
-        ks_ref = vs_ref = None
-    ib = pl.program_id(0)
-    isp, ij = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(ij == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[ib]
-    gj = isp * ppb + ij
-    k_pos = gj * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)
-    valid = (k_pos <= pos) & (gj < n_blocks)
-    if window > 0:
-        valid = jnp.logical_and(valid, k_pos > pos - window)
-
-    @pl.when(jnp.any(valid))
-    def _compute():
-        _online_softmax_update(
-            q_ref[0, 0].astype(jnp.float32),                 # (1, D)
-            _load_kv(k_ref, ks_ref),                         # (ps, D)
-            _load_kv(v_ref, vs_ref),                         # (ps, Dv)
-            valid, m_ref, l_ref, acc_ref, scale=scale, logit_cap=logit_cap)
-
-    @pl.when(ij == ppb - 1)
-    def _flush():
-        _write_partials(part_ref, lse_ref, m_ref, l_ref, acc_ref)
+        out_specs = pl.BlockSpec((1, Hq, 1, Dv),
+                                 lambda b, j, *_: (b, 0, 0, 0))
+        out_shape = jax.ShapeDtypeStruct((B, Hq, 1, Dv), q.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                   # block table + positions
+        grid=grid,
+        in_specs=[pl.BlockSpec((1, Hkv, G, D),
+                               lambda b, *rest: (b, 0, 0, 0))]
+        + [page_spec(i, w) for _, w in pools for i in range(ppc)],
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),    # running max m
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),    # running denom l
+            pltpu.VMEM((Hkv, G, Dv), jnp.float32),   # running numerator
+        ],
+    )
+    kernel = functools.partial(
+        _paged_decode_kernel, scale=scale, window=window,
+        logit_cap=logit_cap, page_size=ps, ppc=ppc, bps=bps,
+        quantized=quantized, partials=partials)
+    return pl.pallas_call(
+        kernel,
+        name="paged_decode_partials" if partials else "paged_decode_sweep",
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(jnp.asarray(block_tables, jnp.int32),
+      jnp.asarray(pos, jnp.int32).reshape(B), q.reshape(B, Hkv, G, D),
+      *(x for x, _ in pools for _ in range(ppc)))
 
 
 def paged_decode_attention_pallas_partials(
@@ -1178,72 +1334,15 @@ def paged_decode_attention_pallas_partials(
     k_scale: jax.Array | None = None,  # (P, ps, Hkv, 1) fp32; int8 pools only
     v_scale: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Stage 1 only: per-split paged decode sweep.  Returns
-    ``(partial (B, Hq, S, 1, Dv) fp32, lse (B, Hq, S, 1) fp32)``."""
-    quantized = k_scale is not None
-    assert quantized == (v_scale is not None), \
-        "k_scale and v_scale must be given together"
-    B, _, Hq, D = q.shape
-    ps, Hkv = k_pages.shape[1], k_pages.shape[2]
-    Dv = v_pages.shape[-1]
-    nb = block_tables.shape[1]
-    G = Hq // Hkv
-    if scale is None:
-        scale = D ** -0.5
-    n_splits, ppb = _split_blocks(nb, n_splits)
-
-    qt = q.transpose(0, 2, 1, 3)                 # (B, Hq, 1, D)
-    kt = k_pages.transpose(0, 2, 1, 3)           # (P, Hkv, ps, D)
-    vt = v_pages.transpose(0, 2, 1, 3)           # (P, Hkv, ps, Dv)
-    bt = jnp.asarray(block_tables, jnp.int32)
-    pos_arr = jnp.asarray(pos, jnp.int32).reshape(B)
-
-    kernel = functools.partial(
-        _paged_decode_partials_kernel, scale=scale, window=window,
-        logit_cap=logit_cap, page_size=ps, n_blocks=nb, ppb=ppb,
-        quantized=quantized)
-
-    def kv_index(b, h, s, j, bt_ref, pos_ref, G=G, ppb=ppb, nb=nb):
-        return (bt_ref[b, jnp.minimum(s * ppb + j, nb - 1)], h // G, 0, 0)
-
-    in_specs = [pl.BlockSpec((1, 1, 1, D),
-                             lambda b, h, s, j, bt_ref, pos_ref: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, ps, D), kv_index)]
-    inputs = [qt, kt]
-    if quantized:              # scale blocks ride the k/v index_map
-        in_specs.append(pl.BlockSpec((1, 1, ps, 1), kv_index))
-        inputs.append(k_scale.transpose(0, 2, 1, 3))     # (P, Hkv, ps, 1)
-    in_specs.append(pl.BlockSpec((1, 1, ps, Dv), kv_index))
-    inputs.append(vt)
-    if quantized:
-        in_specs.append(pl.BlockSpec((1, 1, ps, 1), kv_index))
-        inputs.append(v_scale.transpose(0, 2, 1, 3))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                   # block table + positions
-        grid=(B, Hq, n_splits, ppb),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, 1, Dv),
-                         lambda b, h, s, j, bt_ref, pos_ref: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1, 1),
-                         lambda b, h, s, j, bt_ref, pos_ref: (b, h, s, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),       # running max m
-            pltpu.VMEM((1,), jnp.float32),       # running denom l
-            pltpu.VMEM((1, Dv), jnp.float32),    # running numerator
-        ],
-    )
-    partial, lse = pl.pallas_call(
-        kernel,
-        name="paged_decode_partials",
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, Hq, n_splits, 1, Dv), jnp.float32),
-                   jax.ShapeDtypeStruct((B, Hq, n_splits, 1, 1), jnp.float32)],
-        interpret=interpret,
-    )(bt, pos_arr, *inputs)
-    return partial, lse[:, :, :, 0]
+    """Stage 1 only: per-split paged decode sweep, splits cutting page
+    blocks.  Returns ``(partial (B, Hq, S, 1, Dv) fp32, lse (B, Hq, S, 1)
+    fp32)``."""
+    partial, lse = _paged_decode_call(
+        q, k_pages, v_pages, block_tables, pos, n_splits=n_splits,
+        window=window, logit_cap=logit_cap, scale=scale, interpret=interpret,
+        k_scale=k_scale, v_scale=v_scale)
+    return (partial.transpose(0, 2, 1, 3)[:, :, :, None, :],
+            lse.transpose(0, 2, 1, 3))
 
 
 def paged_decode_attention_pallas(
@@ -1258,19 +1357,19 @@ def paged_decode_attention_pallas(
     k_scale: jax.Array | None = None,  # (P, ps, Hkv, 1) fp32; int8 pools only
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
-    """Split-K decode attention over a paged KV cache.
+    """Decode attention over a paged KV cache, read from the pool as stored.
 
-    Same online-softmax accumulator discipline as the ring kernel, but the
-    k/v ``index_map`` gathers through the scalar-prefetched block table:
-    grid step ``(b, h, j)`` DMAs physical page ``block_tables[b, j]`` for kv
-    head ``h // G``.  The pool is shared across requests — a request's pages
-    need not be contiguous, only its table row must list them in logical
-    order.  ``pos`` is per-request (ragged batch), so validity masks are
-    per-row, unlike the ring kernel's single scalar.  ``n_splits > 1`` runs
-    the two-stage pipeline; ``n_splits = 1`` is the original sweep.
-    ``k_scale``/``v_scale`` flag an int8 pool: per-row fp32 scale blocks
-    ride the same block-table gather and the dequant multiply is fused
-    into the block load (int8 -> fp32 cast is free on the DMA'd tile)."""
+    Grid step ``(b, g)`` covers ``ppc`` consecutive table entries of slot
+    ``b`` for every kv head and query head (GQA folds in as ``G = Hq //
+    Hkv`` query rows per kv head), gathering the pages through the
+    scalar-prefetched block table with one DMA each.  The pool is shared
+    across requests — a request's pages need not be contiguous, only its
+    table row must list them in logical order.  ``pos`` is per-request
+    (ragged batch), so masks and the block range are per slot.
+    ``n_splits > 1`` runs the two-stage pipeline; ``n_splits = 1`` is the
+    single sweep.  ``k_scale``/``v_scale`` flag an int8 pool: the per-row
+    fp32 scales ride the same page DMAs and the dequant multiply is fused
+    into the block load."""
     if n_splits > 1:
         partial, lse = paged_decode_attention_pallas_partials(
             q, k_pages, v_pages, block_tables, pos, n_splits=n_splits,
@@ -1279,62 +1378,10 @@ def paged_decode_attention_pallas(
         out = merge_kv_splits_pallas(partial, lse, out_dtype=q.dtype,
                                      interpret=interpret)   # (B, Hq, 1, Dv)
         return out.transpose(0, 2, 1, 3)                    # (B, 1, Hq, Dv)
-    quantized = k_scale is not None
-    assert quantized == (v_scale is not None), \
-        "k_scale and v_scale must be given together"
-    B, _, Hq, D = q.shape
-    ps, Hkv = k_pages.shape[1], k_pages.shape[2]
-    Dv = v_pages.shape[-1]
-    nb = block_tables.shape[1]
-    G = Hq // Hkv
-    if scale is None:
-        scale = D ** -0.5
-
-    qt = q.transpose(0, 2, 1, 3)                 # (B, Hq, 1, D)
-    kt = k_pages.transpose(0, 2, 1, 3)           # (P, Hkv, ps, D)
-    vt = v_pages.transpose(0, 2, 1, 3)           # (P, Hkv, ps, Dv)
-    bt = jnp.asarray(block_tables, jnp.int32)
-    pos_arr = jnp.asarray(pos, jnp.int32).reshape(B)
-
-    kernel = functools.partial(
-        _paged_decode_kernel, scale=scale, window=window, logit_cap=logit_cap,
-        page_size=ps, n_blocks=nb, quantized=quantized)
-
-    def kv_index(b, h, j, bt_ref, pos_ref, G=G):
-        return (bt_ref[b, j], h // G, 0, 0)
-
-    in_specs = [pl.BlockSpec((1, 1, 1, D),
-                             lambda b, h, j, bt_ref, pos_ref: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, ps, D), kv_index)]
-    inputs = [qt, kt]
-    if quantized:              # scale blocks ride the k/v index_map
-        in_specs.append(pl.BlockSpec((1, 1, ps, 1), kv_index))
-        inputs.append(k_scale.transpose(0, 2, 1, 3))     # (P, Hkv, ps, 1)
-    in_specs.append(pl.BlockSpec((1, 1, ps, Dv), kv_index))
-    inputs.append(vt)
-    if quantized:
-        in_specs.append(pl.BlockSpec((1, 1, ps, 1), kv_index))
-        inputs.append(v_scale.transpose(0, 2, 1, 3))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                   # block table + positions
-        grid=(B, Hq, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, Dv),
-                               lambda b, h, j, bt_ref, pos_ref: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),       # running max m
-            pltpu.VMEM((1,), jnp.float32),       # running denom l
-            pltpu.VMEM((1, Dv), jnp.float32),    # running numerator
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="paged_decode_sweep",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, Dv), q.dtype),
-        interpret=interpret,
-    )(bt, pos_arr, *inputs)
+    out = _paged_decode_call(
+        q, k_pages, v_pages, block_tables, pos, n_splits=None, window=window,
+        logit_cap=logit_cap, scale=scale, interpret=interpret,
+        k_scale=k_scale, v_scale=v_scale)
     return out.transpose(0, 2, 1, 3)             # (B, 1, Hq, Dv)
 
 

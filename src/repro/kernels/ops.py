@@ -561,9 +561,11 @@ def paged_decode_attention(
     if backend == "auto":
         backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
     ps, nb = k_pages.shape[1], block_tables.shape[1]
+    # q_heads = 1: the Pallas sweep tiles every head of a slot in one grid
+    # cell, so the stage-1 grid has B cells per split (as for MLA)
     n_splits = _resolve_kv_splits(policy, q.shape[0],
                                   effective_kv_len(nb * ps, window),
-                                  q.shape[2], block=ps)
+                                  1, block=ps)
     if backend in ("pallas", "pallas_interpret"):
         from repro.kernels import decode_attention as da
         return da.paged_decode_attention_pallas(

@@ -199,13 +199,14 @@ def paged_decode_attention_split_ref(
     v_pages: jax.Array,            # (P, ps, Hkv, Dv)
     block_tables: jax.Array,       # (B, nb) int32
     pos: jax.Array,                # (B,) per-request absolute position of q
-    *, n_splits: int,
+    *, n_splits: int, block_pages: int = 1,
     window: int = 0, logit_cap: float = 0.0, scale: float | None = None,
     k_scale: jax.Array | None = None,  # (P, ps, Hkv, 1) fp32; int8 pools only
     v_scale: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Stage-1 oracle for ``paged_decode_attention_pallas_partials``: pages
-    gathered into logical order, split over pages (the DMA unit)."""
+    gathered into logical order, split over blocks of ``block_pages`` pages
+    (the kernel's grid step, ``decode_attention.pages_per_block``)."""
     B, _, Hq, D = q.shape
     ps, Hkv = k_pages.shape[1], k_pages.shape[2]
     nb = block_tables.shape[1]
@@ -225,7 +226,12 @@ def paged_decode_attention_split_ref(
     if window > 0:
         valid &= k_pos > posb - window
     s = jnp.where(valid[:, None, None], s, NEG_INF)
-    return _split_partials(s, vg, n_units=nb, unit=ps, n_splits=n_splits)
+    n_blk = -(-nb // block_pages)
+    pad = n_blk * block_pages - nb           # the last block's missing pages
+    s = jnp.pad(s, ((0, 0),) * 3 + ((0, pad * ps),), constant_values=NEG_INF)
+    vg = jnp.pad(vg, ((0, 0), (0, pad * ps), (0, 0), (0, 0)))
+    return _split_partials(s, vg, n_units=n_blk, unit=block_pages * ps,
+                           n_splits=n_splits)
 
 
 # --------------------------------------------------------------------------

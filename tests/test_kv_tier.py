@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from repro import quant
+from repro.kernels import decode_attention as da
 from repro.kernels import ops, ref
 
 C = 80                 # ring capacity: 5 blocks of 16
@@ -125,8 +126,16 @@ def test_ring_verify_int8_matches_quantized_oracle(Hq, Hkv, backend,
 @pytest.mark.parametrize("Hq,Hkv", _HEADS)
 @pytest.mark.parametrize("backend", _BACKENDS)
 @pytest.mark.parametrize("n_splits", [1, 2])
+@pytest.mark.parametrize("ppc", [None, 2])
 def test_paged_decode_int8_matches_quantized_oracle(Hq, Hkv, backend,
-                                                    n_splits):
+                                                    n_splits, ppc,
+                                                    monkeypatch):
+    if ppc is not None:
+        # the Pallas sweep's pages per grid step, forced through its VMEM
+        # tile budget: 2 pages a block, the table's last block short
+        token = da._vmem_bytes(Hkv, D, 1) + da._vmem_bytes(Hkv, 1, 4)
+        monkeypatch.setattr(da, "_KV_TILE_BYTES", ppc * PS * token)
+        assert da.pages_per_block(PS, Hkv, D, 1, NB, quantized=True) == ppc
     a = _arrays(3, Hq, Hkv, seed=Hq * 30 + n_splits)
     pos = jnp.asarray([3, 37, 79], jnp.int32)          # ragged occupancy
     oracle = ref.paged_decode_attention_ref(a["q1"], a["kp"], a["vp"],

@@ -136,18 +136,61 @@ def test_ring_verify_two_stage_parity(Hq, Hkv, pos_kind, n_splits):
 # --------------------------------------------------------------------------
 # paged decode / paged verify (ragged per-request pos)
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("Hq,Hkv", _HEADS)
+# paged decode grid: one step per slot x block of ``ppc`` pages, all heads.
+# ``ppc`` follows from the page's VMEM footprint; at these test widths the
+# whole 5-page table is one block, so the tests also shrink the tile budget
+# to force 2 pages a block (3 blocks, the last one short).
+_PAGED_HEADS = _HEADS + [(4, 4), (6, 2)]        # + MHA (G = 1) and G = 3
+_PPC = [None, 2]
+# slot ragged depths: pos 0, a page's last and next position, a 2-page
+# block's last and next position, the table's last position, a mid-page
+# tail; then a parked slot whose whole row points at one scratch page
+_PAGED_POS = [0, 15, 16, 31, 32, NB * PS - 1, 45, 0]
+_PAGED_CASES = {"plain": {}, "window": {"window": 20},
+                "cap": {"logit_cap": 5.0}}
+
+
+def _force_ppc(monkeypatch, ppc, Hkv, itemsize=4, quantized=False):
+    """Pages per block the kernel derives, forced through its tile budget;
+    ``None`` leaves the derivation alone."""
+    if ppc is None:
+        return da.pages_per_block(PS, Hkv, D, itemsize, NB,
+                                  quantized=quantized)
+    token = da._vmem_bytes(Hkv, D, itemsize) \
+        + (da._vmem_bytes(Hkv, 1, 4) if quantized else 0)
+    monkeypatch.setattr(da, "_KV_TILE_BYTES", ppc * PS * token)
+    assert da.pages_per_block(PS, Hkv, D, itemsize, NB,
+                              quantized=quantized) == ppc
+    return ppc
+
+
+def _paged_arrays(Hq, Hkv, *, seed):
+    B = len(_PAGED_POS)
+    rng = np.random.default_rng(seed)
+    r = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    P = B * NB + 1
+    bt = rng.permutation(P - 1)[:B * NB].reshape(B, NB)
+    bt[-1] = P - 1                                   # parked on scratch
+    return {"q1": r(B, 1, Hq, D), "kp": r(P, PS, Hkv, D),
+            "vp": r(P, PS, Hkv, DV), "bt": jnp.asarray(bt, jnp.int32),
+            "pos": jnp.asarray(_PAGED_POS, jnp.int32),
+            "head": r(Hq * DV, 64)}
+
+
+@pytest.mark.parametrize("Hq,Hkv", _PAGED_HEADS)
 @pytest.mark.parametrize("n_splits", SPLITS)
-def test_paged_decode_two_stage_parity(Hq, Hkv, n_splits):
-    a = _rng_arrays(3, Hq, Hkv, seed=Hq * 30 + n_splits)
-    pos = jnp.asarray([3, 37, 79], jnp.int32)      # ragged occupancy
-    oracle = ref.paged_decode_attention_ref(a["q1"][:3], a["kp"], a["vp"],
-                                            a["bt"], pos)
-    single = da.paged_decode_attention_pallas(a["q1"][:3], a["kp"], a["vp"],
-                                              a["bt"], pos, interpret=True)
-    two = da.paged_decode_attention_pallas(a["q1"][:3], a["kp"], a["vp"],
-                                           a["bt"], pos, n_splits=n_splits,
-                                           interpret=True)
+@pytest.mark.parametrize("ppc", _PPC)
+@pytest.mark.parametrize("case", list(_PAGED_CASES))
+def test_paged_decode_two_stage_parity(Hq, Hkv, n_splits, ppc, case,
+                                       monkeypatch):
+    _force_ppc(monkeypatch, ppc, Hkv)
+    a = _paged_arrays(Hq, Hkv, seed=Hq * 30 + Hkv + n_splits)
+    kw = _PAGED_CASES[case]
+    args = (a["q1"], a["kp"], a["vp"], a["bt"], a["pos"])
+    oracle = ref.paged_decode_attention_ref(*args, **kw)
+    single = da.paged_decode_attention_pallas(*args, interpret=True, **kw)
+    two = da.paged_decode_attention_pallas(*args, n_splits=n_splits,
+                                           interpret=True, **kw)
     assert float(jnp.max(jnp.abs(two - oracle))) < TOL
     assert float(jnp.max(jnp.abs(two - single))) < TOL
     assert bool(jnp.all(_argmax(two, a["head"]) == _argmax(oracle, a["head"])))
@@ -156,15 +199,17 @@ def test_paged_decode_two_stage_parity(Hq, Hkv, n_splits):
 
 
 @pytest.mark.parametrize("n_splits", [2, 5])
-def test_paged_decode_stage1_matches_split_ref(n_splits):
-    a = _rng_arrays(3, 4, 2, seed=7)
-    pos = jnp.asarray([3, 37, 79], jnp.int32)
+@pytest.mark.parametrize("ppc", _PPC)
+def test_paged_decode_stage1_matches_split_ref(n_splits, ppc, monkeypatch):
+    ppc = _force_ppc(monkeypatch, ppc, 2)
+    a = _paged_arrays(4, 2, seed=7)
+    args = (a["q1"], a["kp"], a["vp"], a["bt"], a["pos"])
     pr, lr = ref.paged_decode_attention_split_ref(
-        a["q1"][:3], a["kp"], a["vp"], a["bt"], pos, n_splits=n_splits)
+        *args, n_splits=n_splits, block_pages=ppc)
     pp, lp = da.paged_decode_attention_pallas_partials(
-        a["q1"][:3], a["kp"], a["vp"], a["bt"], pos, n_splits=n_splits,
-        interpret=True)
+        *args, n_splits=n_splits, interpret=True)
     assert pr.shape == pp.shape and lr.shape == lp.shape
+    assert pp.shape[2] == min(n_splits, -(-NB // ppc))
     assert float(jnp.max(jnp.abs(pr - pp))) < TOL
     assert float(jnp.max(jnp.abs(lr - lp))) < 1e-5
 

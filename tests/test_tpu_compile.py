@@ -27,6 +27,9 @@ HBM_BYTES = 16 * 10**9          # one v5e chip
 # SmolLM-135M serving shapes: 16 slots x 2048 tokens in pages of 16
 B, HQ, HKV, D, PS, NB, Q = 16, 9, 3, 64, 16, 128, 4
 N_PAGES = B * NB + B
+# StableLM-2-1.6B batch shapes: 8 slots x 2048 tokens, MHA 32/32
+SB, SHQ = 8, 32
+S_PAGES = SB * NB + SB
 # DeepSeek-V2 latent widths at batch 1
 MLA_HQ, MLA_R, MLA_RKV, MLA_NB = 128, 576, 512, 256
 
@@ -53,6 +56,25 @@ def _shape(one_chip, shape, dtype):
 
 def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
+
+
+def _hlo_with_operand_shapes(compiled) -> str:
+    """The compiled module's text with each operand's shape, as a device
+    trace names an op."""
+    from jax._src.lib import xla_client
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    return compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+
+
+def _decode_sweep_signature(b: int, hq: int, d: int, nb: int):
+    """The HLO signature the benchmark's kernel-roofline reader picks the
+    paged decode sweep by: result ``bf16[B,Hq,1,D]``, operands starting
+    with the ``s32[B,nb]`` block table and the ``s32[B]`` positions."""
+    import re
+    return re.compile(
+        r"= bf16\[%d,%d,1,%d\]\{[^}]*\} custom-call\(s32\[%d,%d\]\{[^}]*\} "
+        r"[^,]+, s32\[%d\]" % (b, hq, d, b, nb, b))
 
 
 def _kernels(compiled) -> set[str]:
@@ -106,6 +128,43 @@ def test_gqa_sweep_compiles(one_chip, name, n_splits):
         else {f"{family}_partials", "split_kv_merge"})
 
 
+@pytest.mark.parametrize("cell", ["smollm_chat", "stablelm_batch"])
+def test_paged_decode_sweep_signature(one_chip, cell):
+    """The single-stage paged decode sweep at each benchmark cell's shape
+    compiles, is named, keeps the signature the benchmark reads, and takes
+    the pool as stored: no transposed or relaid-out pool feeds it."""
+    b, hq, hkv, n_pages = ((B, HQ, HKV, N_PAGES) if cell == "smollm_chat"
+                           else (SB, SHQ, SHQ, S_PAGES))
+    S = lambda shape, dt=jnp.bfloat16: _shape(one_chip, shape, dt)
+    compiled = _compile(
+        lambda q, k, v, t, p: da.paged_decode_attention_pallas(q, k, v, t, p),
+        S((b, 1, hq, D)), S((n_pages, PS, hkv, D)), S((n_pages, PS, hkv, D)),
+        S((b, NB), jnp.int32), S((b,), jnp.int32))
+    assert _kernels(compiled) == {"paged_decode_sweep"}
+    text = _hlo_with_operand_shapes(compiled)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert _decode_sweep_signature(b, hq, D, NB).search(calls[0])
+    assert f"bf16[{n_pages},{hkv},{PS},{D}]" not in text
+
+
+def test_paged_decode_grid_steps():
+    """Grid steps per call are bounded by slots x page blocks x splits at
+    both cells' shapes, and a step covers 128-512 tokens there."""
+    for b, hkv in ((B, HKV), (SB, SHQ)):
+        ppc = da.pages_per_block(PS, hkv, D, 2, NB)
+        assert 128 <= ppc * PS <= 512
+        for n_splits in (None, 1, 2, 5):
+            grid = da.paged_decode_grid(b, NB, ppc, n_splits)
+            steps = 1
+            for n in grid:
+                steps *= n
+            assert steps <= b * -(-NB // ppc) * (n_splits or 1)
+            assert grid[0] == b and len(grid) == (2 if n_splits is None
+                                                  else 3)
+
+
 @pytest.mark.parametrize("n_splits", [1, 4])
 def test_mla_paged_decode_compiles(one_chip, n_splits):
     S = lambda shape, dt=jnp.bfloat16: _shape(one_chip, shape, dt)
@@ -147,6 +206,9 @@ def test_smollm_paged_decode_loop_compiles_and_fits(one_chip):
                           _shape(one_chip, (B,), jnp.int32)).compile()
     assert compiled.as_text().startswith("HloModule jit_decode_loop")
     assert _kernels(compiled) == {"paged_decode_sweep"}
+    # the sweep reads the pool as stored: no instruction of the loop
+    # produces a head-major (transposed) pool
+    assert f"bf16[{N_PAGES},{HKV},{PS},{D}]" not in compiled.as_text()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
