@@ -1,10 +1,10 @@
 """Architecture registry: ``--arch <id>`` resolution for every launcher."""
 from __future__ import annotations
 
-from repro.configs import (deepseek_v2_236b, gemma2_27b, h2o_danube3_4b,
-                           llava_next_34b, mamba2_370m, musicgen_medium,
-                           phi35_moe, smollm_135m, stablelm_1_6b,
-                           zamba2_1_2b)
+from repro.configs import (deepseek_v2_236b, deepseek_v2_lite, gemma2_27b,
+                           h2o_danube3_4b, llava_next_34b, mamba2_370m,
+                           musicgen_medium, phi35_moe, smollm_135m,
+                           stablelm_1_6b, zamba2_1_2b)
 from repro.configs.base import ArchSpec, reduce_for_smoke
 from repro.configs.shapes import ALL_SHAPES, SHAPES, Shape
 
@@ -18,6 +18,7 @@ ARCH_SPECS: dict[str, ArchSpec] = {
         musicgen_medium.SPEC,
         phi35_moe.SPEC,
         deepseek_v2_236b.SPEC,
+        deepseek_v2_lite.SPEC,
         llava_next_34b.SPEC,
         mamba2_370m.SPEC,
         zamba2_1_2b.SPEC,

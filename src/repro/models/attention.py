@@ -367,6 +367,13 @@ def init_mla(key, cfg: ModelConfig) -> dict[str, Any]:
     return p
 
 
+def _mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale over the [nope | rope] query-key dims, times YaRN's
+    ``mscale_all_dim`` correction where the config scales its rope."""
+    base = cfg.query_scale or (cfg.nope_head_dim + cfg.rope_head_dim) ** -0.5
+    return base * common.yarn_softmax_factor(cfg.rope_scaling)
+
+
 def _mla_queries(params, x, positions, cfg: ModelConfig):
     adt = x.dtype
     dn, dr = cfg.nope_head_dim, cfg.rope_head_dim
@@ -377,7 +384,8 @@ def _mla_queries(params, x, positions, cfg: ModelConfig):
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, params["wuq"].astype(adt))
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = common.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = common.apply_rope(q_rope, positions, cfg.rope_theta,
+                               cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -389,7 +397,7 @@ def _mla_latent(params, x, positions, cfg: ModelConfig):
     c_kv, k_rope = ckv_rope[..., :r_kv], ckv_rope[..., r_kv:]
     c_kv = common.rmsnorm(c_kv, params["kv_norm"], cfg.norm_eps)
     k_rope = common.apply_rope(k_rope[:, :, None, :], positions,
-                               cfg.rope_theta)[:, :, 0, :]
+                               cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
     return c_kv, k_rope
 
 
@@ -399,7 +407,7 @@ def mla_forward(params, x, positions, cfg: ModelConfig, *,
     """Training/prefill MLA: expand the latent to per-head k/v, run GQA-style
     flash attention with concatenated [nope|rope] q/k."""
     adt = x.dtype
-    dn, dr, dv = cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
+    dr = cfg.rope_head_dim
     q_nope, q_rope = _mla_queries(params, x, positions, cfg)
     c_kv, k_rope = _mla_latent(params, x, positions, cfg)
 
@@ -414,7 +422,7 @@ def mla_forward(params, x, positions, cfg: ModelConfig, *,
         q = constrain(q, ("batch", None, "q_heads", None))
         k = constrain(k, ("batch", None, "q_heads", None))
         v = constrain(v, ("batch", None, "q_heads", None))
-    scale = cfg.query_scale or (dn + dr) ** -0.5
+    scale = _mla_scale(cfg)
     o = ops.attention(q, k, v, causal=True, scale=scale,
                       logit_cap=cfg.attn_logit_softcap, policy=policy)
     out = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(adt))
@@ -455,7 +463,7 @@ def mla_decode(params, x, pos, cache_lat, cfg: ModelConfig, *,
     paged jnp path runs, which is what keeps paged greedy streams on the
     ring reference's argmax."""
     adt = x.dtype
-    r_kv, dr, dn = cfg.kv_lora_rank, cfg.rope_head_dim, cfg.nope_head_dim
+    r_kv = cfg.kv_lora_rank
     C = cache_lat.shape[1]
     posb = jnp.asarray(pos)[None]
     q_nope, q_rope = _mla_queries(params, x, posb, cfg)      # (B,1,H,*)
@@ -468,7 +476,7 @@ def mla_decode(params, x, pos, cache_lat, cfg: ModelConfig, *,
 
     # absorb W_uk into the query:  (B,1,H,dn) @ (r,H,dn) -> (B,H,r)
     q_abs = jnp.einsum("bshk,rhk->bhr", q_nope, params["wuk"].astype(adt))
-    scale = cfg.query_scale or (dn + dr) ** -0.5
+    scale = _mla_scale(cfg)
     k_pos = pos - jnp.mod(pos - jnp.arange(C), C)
     valid = (k_pos >= 0) & (k_pos <= pos)
     o_lat = ops.mla_absorbed_attend_jnp(
@@ -497,7 +505,7 @@ def mla_decode_paged(params, x, pos, cache_lat, block_tables,
     the latent row serves scores AND values, so one page DMA feeds all
     heads."""
     adt = x.dtype
-    r_kv, dr, dn = cfg.kv_lora_rank, cfg.rope_head_dim, cfg.nope_head_dim
+    r_kv = cfg.kv_lora_rank
     P, ps, R = cache_lat.shape
     posb = jnp.asarray(pos)[:, None]                       # (B, 1)
     q_nope, q_rope = _mla_queries(params, x, posb, cfg)    # (B,1,H,*)
@@ -512,7 +520,7 @@ def mla_decode_paged(params, x, pos, cache_lat, block_tables,
 
     q_abs = jnp.einsum("bshk,rhk->bhr", q_nope, params["wuk"].astype(adt))
     q_lat = jnp.concatenate([q_abs[:, None], q_rope], axis=-1)   # (B,1,H,R)
-    scale = cfg.query_scale or (dn + dr) ** -0.5
+    scale = _mla_scale(cfg)
     o_lat = ops.mla_decode_paged(q_lat, cache_lat, block_tables, pos,
                                  r_kv=r_kv, scale=scale,
                                  logit_cap=cfg.attn_logit_softcap,
@@ -535,7 +543,7 @@ def mla_verify_paged(params, x, pos, cache_lat, block_tables,
     kernel) serves MLA unchanged.  Returns the pending latent rows
     (B, Q, R) for the caller's masked commit; the pool stays untouched."""
     adt = x.dtype
-    r_kv, dr, dn = cfg.kv_lora_rank, cfg.rope_head_dim, cfg.nope_head_dim
+    r_kv = cfg.kv_lora_rank
     Q = x.shape[1]
     posq = jnp.asarray(pos)[:, None] + jnp.arange(Q)[None, :]    # (B, Q)
     q_nope, q_rope = _mla_queries(params, x, posq, cfg)          # (B,Q,H,*)
@@ -544,7 +552,7 @@ def mla_verify_paged(params, x, pos, cache_lat, block_tables,
     lat_new = jnp.concatenate([c_kv, k_rope], axis=-1)           # (B, Q, R)
     q_abs = jnp.einsum("bqhk,rhk->bqhr", q_nope, params["wuk"].astype(adt))
     q_lat = jnp.concatenate([q_abs, q_rope], axis=-1)            # (B,Q,H,R)
-    scale = cfg.query_scale or (dn + dr) ** -0.5
+    scale = _mla_scale(cfg)
     o_lat = ops.paged_verify_attention(
         q_lat, cache_lat[:, :, None, :], cache_lat[:, :, None, :r_kv],
         lat_new[:, :, None, :].astype(adt),

@@ -7,6 +7,8 @@ path-pattern match over the param tree.
 """
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -94,20 +96,70 @@ def softcap(x: jax.Array, cap: float) -> jax.Array:
 # --------------------------------------------------------------------------
 # RoPE
 # --------------------------------------------------------------------------
-def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
-    return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (``rope_scaling`` of
+    type "yarn"): frequencies below ``beta_slow`` rotations over the
+    original context are interpolated by ``factor``, those above
+    ``beta_fast`` kept, with a linear ramp between; cos/sin are scaled by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)`` and the
+    attention softmax by ``mscale(factor, mscale_all_dim) ** 2``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_softmax_factor(scaling: YaRN | None) -> float:
+    """What YaRN multiplies the attention softmax scale by (1 without)."""
+    if scaling is None or not scaling.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def rope_frequencies(head_dim: int, theta: float,
+                     scaling: YaRN | None = None) -> np.ndarray:
+    extra = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+    if scaling is None:
+        return extra
+
+    def correction_dim(rotations):
+        return head_dim * math.log(scaling.original_max_position
+                                   / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0.0, 1.0)
+    return extra / scaling.factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0,
+               scaling: YaRN | None = None) -> jax.Array:
     """Rotate pairs (x[..., :d/2], x[..., d/2:]) — llama convention.
 
     x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
     """
     head_dim = x.shape[-1]
-    freqs = jnp.asarray(rope_frequencies(head_dim, theta), dtype=jnp.float32)
+    freqs = jnp.asarray(rope_frequencies(head_dim, theta, scaling),
+                        dtype=jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs          # (..., S, hd/2)
     cos = jnp.cos(angles)[..., None, :]                                # (..., S, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) \
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim)
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

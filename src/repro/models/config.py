@@ -12,6 +12,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Literal
 
+from repro.models.common import YaRN
+
 BlockKind = Literal["attn", "mamba"]
 
 
@@ -32,6 +34,7 @@ class ModelConfig:
     # -- attention flavour -----------------------------------------------------
     rope_theta: float = 10_000.0
     rope_fraction: float = 1.0         # stablelm2: partial rotary (0.25)
+    rope_scaling: YaRN | None = None   # deepseek-v2: YaRN on the MLA rope dims
     sliding_window: int = 0            # >0 -> SWA on every attn layer (danube3)
     local_global: bool = False         # gemma2: alternate local/global layers
     local_window: int = 4096           # window of the local layers
@@ -54,6 +57,9 @@ class ModelConfig:
     v_head_dim: int = 128
     # -- MoE ---------------------------------------------------------------------
     n_experts: int = 0                 # routed experts (0 -> dense MLP)
+    held_experts: int = 0              # experts 0..held-1 computed here (0 -> all);
+                                       # the router still spans n_experts
+    norm_topk_prob: bool = True        # renormalise the top-k gates to sum 1
     n_shared_experts: int = 0
     experts_per_token: int = 0         # top-k
     moe_d_ff: int = 0                  # routed-expert hidden (0 -> d_ff)
@@ -106,6 +112,11 @@ class ModelConfig:
                 raise ValueError("padding maps real q heads to padded kv")
         if self.n_experts and not self.experts_per_token:
             raise ValueError("MoE needs experts_per_token")
+        if not 0 <= self.held_experts <= self.n_experts:
+            raise ValueError("held_experts must lie in [0, n_experts]")
+        if self.rope_scaling is not None and not self.use_mla:
+            raise ValueError("rope_scaling (YaRN) is implemented for latent "
+                             "attention (use_mla) only")
         if self.family in ("ssm", "hybrid") and not self.ssm_state:
             raise ValueError("SSM family needs ssm_state > 0")
 
@@ -140,6 +151,10 @@ class ModelConfig:
     @property
     def resolved_moe_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def resolved_held_experts(self) -> int:
+        return self.held_experts or self.n_experts
 
     @property
     def resolved_shared_d_ff(self) -> int:

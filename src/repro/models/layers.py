@@ -1,20 +1,28 @@
 """Feed-forward layers: dense SwiGLU MLP and expert-parallel MoE.
 
-MoE dispatch is the TPU-native adaptation of GShard top-k routing:
+MoE dispatch is sort-then-grouped-matmul, with no capacity and no dropped
+token: the router spans all ``n_experts``, each token keeps its top-k, the
+(token, choice) rows routed to the experts held here are sorted by expert
+and run through ``jax.lax.ragged_dot`` (one grouped matmul per expert
+weight), and the rows come back to their tokens weighted by their gates.
+Rows routed to experts held elsewhere are computed nowhere here: that is
+the chip-share cut (``ModelConfig.held_experts``), whose partial result
+goes on to the next layer as an expert-parallel chip's would before its
+exchange.
 
-  * tokens are batch-sharded over ("pod","data") and replicated over "model";
-  * experts are sharded over "model" (EP).  Inside a shard_map, each model
-    shard selects the tokens routed to ITS experts with a one-hot-cumsum
-    capacity assignment (no all-to-all — selection is local because tokens
-    are replicated on the model axis), runs its expert FFNs as one batched
-    einsum (MXU-friendly (E_loc, Cap, d) x (E_loc, d, ff)), and the combine
-    is a single psum over "model" — the same all-reduce pattern Megatron TP
-    uses, so MoE adds no new collective phase.
-  * shared experts (DeepSeek) are computed in the same shard_map with their
-    hidden dim sliced over "model", folded into the same psum.
-
-Without a mesh (CPU smoke tests) the same math runs with E_loc = E and the
-psum elided — bit-identical routing decisions.
+  * Without a mesh the layer holds ``held_experts`` experts (all of them by
+    default) and returns their part of the result plus the shared experts.
+  * With a mesh, tokens are batch-sharded over ("pod","data") and
+    replicated over "model"; the held experts are sharded over "model"
+    (EP).  Inside a shard_map each model shard runs the same dispatch over
+    ITS experts (selection is local because tokens are replicated on the
+    model axis), and the combine is a single psum over "model" — the same
+    all-reduce pattern Megatron TP uses, so MoE adds no new collective
+    phase.  Shared experts (DeepSeek) are computed in the same shard_map
+    with their hidden dim sliced over "model", folded into the same psum.
+  * ``moe_strategy="a2a"`` (``moe_forward_a2a``) owns experts by data rank
+    and exchanges tokens; its all-to-all buffers are static, so that path
+    keeps a capacity.
 """
 from __future__ import annotations
 
@@ -93,7 +101,7 @@ def mlp_forward(params, x, cfg: ModelConfig, constrain=None) -> jax.Array:
 # ==========================================================================
 def init_moe(key, cfg: ModelConfig) -> dict[str, Any]:
     d = cfg.d_model
-    E, ff = cfg.n_experts, cfg.resolved_moe_d_ff
+    E, ff = cfg.resolved_held_experts, cfg.resolved_moe_d_ff
     keys = common.split_keys(key, 6)
     dt = cfg.param_dtype
     # expert weights get DEDICATED logical axes so the sharding strategy can
@@ -102,7 +110,7 @@ def init_moe(key, cfg: ModelConfig) -> dict[str, Any]:
     #   a2a:    experts->data (EP ownership), expert_ff->model (Megatron
     #           within-expert TP), expert_d->None — zero weight gathers
     p = {
-        "router": pl_(keys[0], (d, E), ("embed", None), dtype=dt),
+        "router": pl_(keys[0], (d, cfg.n_experts), ("embed", None), dtype=dt),
         "wi_gate": pl_(keys[1], (E, d, ff),
                        ("experts", "expert_d", "expert_ff"), dtype=dt),
         "wi_up": pl_(keys[2], (E, d, ff),
@@ -121,46 +129,41 @@ def init_moe(key, cfg: ModelConfig) -> dict[str, Any]:
     return p
 
 
-def _moe_local(x2d, gates, idx, wi_gate, wi_up, wo, shard_idx, E_loc,
-               capacity, act, keep_dtype):
-    """Dispatch + expert compute for the experts owned by this shard.
+def _grouped_experts(x2d, gates, idx, wi_gate, wi_up, wo, first, act):
+    """The held experts' part of the layer for every token: no capacity,
+    no dropped row.
 
-    x2d: (T, d) local tokens; gates/idx: (T, k) top-k routing.
-    wi_*: (E_loc, d, ff) this shard's experts.  Returns (T, d) partial out.
-    """
+    x2d: (T, d); gates/idx: (T, k) top-k routing over all experts;
+    wi_*: (E_h, d, ff) / wo: (E_h, ff, d) experts ``first .. first+E_h-1``.
+    The (token, choice) rows routed to a held expert are sorted by expert
+    (rows for other experts sort last, outside every group) and run as
+    three grouped matmuls; each row comes back to its token weighted by
+    its gate, the k choices summed in float32.  Returns the (T, d)
+    partial output."""
     T, d = x2d.shape
     k = idx.shape[1]
-    lo = shard_idx * E_loc
-
-    flat_e = idx.reshape(-1) - lo                       # (T*k,)
-    sel = (flat_e >= 0) & (flat_e < E_loc)
-    flat_e = jnp.where(sel, flat_e, 0)
-    oh = jax.nn.one_hot(flat_e, E_loc, dtype=jnp.float32) * sel[:, None]
-    pos = (jnp.cumsum(oh, axis=0) - oh) * oh            # (T*k, E_loc)
-    pos_at = jnp.sum(pos, axis=1).astype(jnp.int32)     # position within expert
-    keep = sel & (pos_at < capacity)
-
-    tok = jnp.repeat(jnp.arange(T), k)
-    slot = flat_e * capacity + pos_at                   # (T*k,)
-    buf = jnp.zeros((E_loc * capacity, d), keep_dtype)
-    contrib = x2d[tok] * keep[:, None].astype(keep_dtype)
-    buf = buf.at[jnp.where(keep, slot, E_loc * capacity)].add(
-        contrib, mode="drop", indices_are_sorted=False)
-    buf = buf.reshape(E_loc, capacity, d)
-
-    h = act(jnp.einsum("ecd,edf->ecf", buf, wi_gate)) * \
-        jnp.einsum("ecd,edf->ecf", buf, wi_up)
-    out_buf = jnp.einsum("ecf,efd->ecd", h, wo).reshape(E_loc * capacity, d)
-
-    gathered = out_buf[jnp.where(keep, slot, 0)] * keep[:, None].astype(keep_dtype)
-    weighted = gathered * gates.reshape(-1)[:, None].astype(keep_dtype)
-    out = jnp.zeros((T, d), keep_dtype).at[tok].add(weighted)
-    return out
+    E_h = wi_gate.shape[0]
+    e = idx.reshape(-1) - first                         # (T*k,)
+    held = (e >= 0) & (e < E_h)
+    order = jnp.argsort(jnp.where(held, e, E_h), stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(e, E_h, dtype=jnp.int32), axis=0)
+    xs = x2d[order // k]
+    h = act(jax.lax.ragged_dot(xs, wi_gate, sizes)) \
+        * jax.lax.ragged_dot(xs, wi_up, sizes)
+    ys = jax.lax.ragged_dot(h, wo, sizes)[jnp.argsort(order)]
+    # rows outside every group are never read: select, not multiply
+    w = jnp.where(held, gates.reshape(-1), 0.0)[:, None]
+    ys = jnp.where(held[:, None], ys.astype(jnp.float32) * w, 0.0)
+    return jnp.sum(ys.reshape(T, k, d), axis=1).astype(x2d.dtype)
 
 
 def moe_forward(params, x, cfg: ModelConfig, ctx: ParallelCtx = NO_MESH,
-                constrain=None):
-    """Top-k MoE FFN.  x: (B, S, d).  Returns (out, aux_loss)."""
+                constrain=None, *, first_expert: int = 0):
+    """Top-k MoE FFN over the held experts.  x: (B, S, d).
+
+    Returns (out, aux_loss, rows): ``rows`` counts the (token, expert) rows
+    the held experts computed.  ``first_expert`` is the first held expert's
+    index among the router's ``n_experts`` (the layer's chip share)."""
     B, S, d = x.shape
     adt = x.dtype
     E, k = cfg.n_experts, cfg.experts_per_token
@@ -183,7 +186,8 @@ def moe_forward(params, x, cfg: ModelConfig, ctx: ParallelCtx = NO_MESH,
                 jnp.arange(gscore.shape[0])[:, None], top_g].set(1.0)
             probs = probs * jnp.repeat(gmask, gsz, axis=1)
     gates, idx = jax.lax.top_k(probs, k)                # (T, k)
-    gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
 
     # Switch-style load-balance aux loss (computed identically on all shards)
     frac_tokens = jnp.mean(
@@ -191,37 +195,35 @@ def moe_forward(params, x, cfg: ModelConfig, ctx: ParallelCtx = NO_MESH,
     frac_probs = jnp.mean(probs, axis=0)
     aux = E * jnp.sum(frac_tokens * frac_probs) * cfg.aux_loss_weight
 
+    E_h = cfg.resolved_held_experts
+    rows = jnp.sum((idx >= first_expert) & (idx < first_expert + E_h),
+                   dtype=jnp.int32)
     if (ctx.mesh is not None and ctx.moe_strategy == "a2a"
             and E % max(ctx.data_size, 1) == 0):
-        return moe_forward_a2a(params, x, cfg, ctx, gates, idx, aux)
-
-    tp = ctx.model_size
-    E_loc = E // tp
-    T_tot = B * S
+        if E_h != E or first_expert:
+            raise ValueError("the all-to-all MoE path owns every expert; "
+                             "a chip share (held_experts) runs without it")
+        out, aux = moe_forward_a2a(params, x, cfg, ctx, gates, idx, aux)
+        return out, aux, rows
 
     if ctx.mesh is None:
-        capacity = _capacity(cfg, k, T_tot, E)
-        out2d = _moe_local(x2d, gates, idx, params["wi_gate"].astype(adt),
-                           params["wi_up"].astype(adt), params["wo"].astype(adt),
-                           0, E, capacity, act, adt)
+        out2d = _grouped_experts(
+            x2d, gates, idx, params["wi_gate"].astype(adt),
+            params["wi_up"].astype(adt), params["wo"].astype(adt),
+            first_expert, act)
         if "shared" in params:
             out2d = out2d + _shared_expert(params["shared"], x2d, act, adt)
-        return out2d.reshape(B, S, d), aux
+        return out2d.reshape(B, S, d), aux, rows
 
     maxis = ctx.model_axis
+    E_loc = E_h // ctx.model_size
     baxes = tuple(a for a in ctx.batch_axes if a in ctx.mesh.shape)
-    n_batch_shards = 1
-    for a in baxes:
-        n_batch_shards *= ctx.mesh.shape[a]
-    T_loc = T_tot // n_batch_shards
-    capacity = _capacity(cfg, k, T_loc, E)
     bspec = P(baxes)          # shard dim 0 of (T, ...) over all batch axes
 
     def shard_fn(x2d_l, gates_l, idx_l, wig, wiu, wog, shared):
-        sidx = jax.lax.axis_index(maxis)
-        out = _moe_local(x2d_l, gates_l, idx_l, wig.astype(adt),
-                         wiu.astype(adt), wog.astype(adt),
-                         sidx, E_loc, capacity, act, adt)
+        lo = first_expert + jax.lax.axis_index(maxis) * E_loc
+        out = _grouped_experts(x2d_l, gates_l, idx_l, wig.astype(adt),
+                               wiu.astype(adt), wog.astype(adt), lo, act)
         if shared is not None:
             out = out + _shared_expert(shared, x2d_l, act, adt)
         return jax.lax.psum(out, maxis)
@@ -241,7 +243,7 @@ def moe_forward(params, x, cfg: ModelConfig, ctx: ParallelCtx = NO_MESH,
         check_vma=False,
     )(x2d, gates, idx, params["wi_gate"], params["wi_up"], params["wo"],
       shared_p)
-    return out2d.reshape(B, S, d), aux
+    return out2d.reshape(B, S, d), aux, rows
 
 
 def _place(dest, sel, capacity, n_dest):
@@ -398,15 +400,6 @@ def moe_forward_a2a(params, x, cfg: ModelConfig, ctx: ParallelCtx,
     )(x2d, gates, idx, params["wi_gate"], params["wi_up"], params["wo"],
       shared_p)
     return out2d.reshape(B, S, d), aux
-
-
-def _capacity(cfg: ModelConfig, k: int, T: int, E: int) -> int:
-    """Expert capacity.  Token dropping is part of capacity-based routing
-    during training, but decode steps (tiny T) must never drop — a dropped
-    token in serving is a quality bug, and the buffer is tiny anyway."""
-    if T <= 256:
-        return T
-    return max(1, min(T, int(cfg.capacity_factor * k * T / E)))
 
 
 def _shared_expert(shared, x2d, act, adt):

@@ -168,6 +168,28 @@ def _norm(x, scale, cfg: ModelConfig):
     return common.rmsnorm(x, scale, cfg.norm_eps, gemma_style=cfg.post_norms)
 
 
+def _ffn(blk, h, cfg: ModelConfig, ctx: RunCtx):
+    """The block's feed-forward: (out, MoE aux loss, rows the held experts
+    computed); a dense MLP gives (out, 0.0, None)."""
+    if "router" in blk["ffn"]:
+        return moe_forward(blk["ffn"], h, cfg, ctx.parallel,
+                           constrain=ctx.constrain)
+    return mlp_forward(blk["ffn"], h, cfg, constrain=ctx.constrain), 0.0, None
+
+
+def _add_rows(total, rows):
+    """Sum of expert-row counts where either may be None (no experts)."""
+    if rows is None:
+        return total
+    return rows if total is None else total + rows
+
+
+def _no_rows(cfg: ModelConfig):
+    """The zero an expert-row count starts from; None without experts, so
+    a dense model computes nothing for it."""
+    return jnp.zeros((), jnp.int32) if cfg.uses_moe else None
+
+
 def _apply_block(blk, x, positions, cfg: ModelConfig, ctx: RunCtx, *,
                  window: int, aux: jax.Array):
     h = _norm(x, blk["norm1"], cfg)
@@ -181,15 +203,10 @@ def _apply_block(blk, x, positions, cfg: ModelConfig, ctx: RunCtx, *,
         a = _norm(a, blk["post_attn_norm"], cfg)
     x = x + a
     h = _norm(x, blk["norm2"], cfg)
-    if "router" in blk["ffn"]:
-        f, aux_l = moe_forward(blk["ffn"], h, cfg, ctx.parallel,
-                               constrain=ctx.constrain)
-        aux = aux + aux_l
-    else:
-        f = mlp_forward(blk["ffn"], h, cfg, constrain=ctx.constrain)
+    f, aux_l, _ = _ffn(blk, h, cfg, ctx)
     if cfg.post_norms:
         f = _norm(f, blk["post_ffn_norm"], cfg)
-    return x + f, aux
+    return x + f, aux + aux_l
 
 
 def _apply_shared_attn(shared, x, emb0, positions, cfg: ModelConfig,
@@ -536,8 +553,9 @@ def init_paged_cache(cfg: ModelConfig, n_slots: int, n_pages: int,
 
 
 def _block_prefill(blk, x, positions, cfg: ModelConfig, ctx: RunCtx, *,
-                   window: int, cache_len: int, aux):
-    """_apply_block that also emits this layer's decode cache."""
+                   window: int, cache_len: int):
+    """_apply_block that also emits this layer's decode cache: returns
+    (x, cache, expert rows | None)."""
     h = _norm(x, blk["norm1"], cfg)
     if cfg.use_mla:
         a, lat = attn.mla_prefill(blk["attn"], h, positions, cfg,
@@ -555,15 +573,10 @@ def _block_prefill(blk, x, positions, cfg: ModelConfig, ctx: RunCtx, *,
         a = _norm(a, blk["post_attn_norm"], cfg)
     x = x + a
     h = _norm(x, blk["norm2"], cfg)
-    if "router" in blk["ffn"]:
-        f, aux_l = moe_forward(blk["ffn"], h, cfg, ctx.parallel,
-                               constrain=ctx.constrain)
-        aux = aux + aux_l
-    else:
-        f = mlp_forward(blk["ffn"], h, cfg, constrain=ctx.constrain)
+    f, _, rows = _ffn(blk, h, cfg, ctx)
     if cfg.post_norms:
         f = _norm(f, blk["post_ffn_norm"], cfg)
-    return x + f, c, aux
+    return x + f, c, rows
 
 
 def prefill(params, tokens, cfg: ModelConfig, ctx: RunCtx = RunCtx(), *,
@@ -573,6 +586,7 @@ def prefill(params, tokens, cfg: ModelConfig, ctx: RunCtx = RunCtx(), *,
 
     Returns (logits, cache) — logits for every prompt position (the serving
     layer samples from the last one); cache['pos'] = prompt length.
+    ``prefill_counted`` also returns the expert rows it computed.
 
     ``full_cache=True`` keeps sliding-window layers' caches LINEAR at
     capacity ``max_len`` instead of wrapping them into an O(window) ring:
@@ -583,6 +597,18 @@ def prefill(params, tokens, cfg: ModelConfig, ctx: RunCtx = RunCtx(), *,
     private ring pages.  Attention masking is unchanged (the window still
     clips scores); only the emitted cache layout differs.
     """
+    return prefill_counted(params, tokens, cfg, ctx, max_len=max_len,
+                           extra_embeds=extra_embeds,
+                           full_cache=full_cache)[:2]
+
+
+def prefill_counted(params, tokens, cfg: ModelConfig, ctx: RunCtx = RunCtx(),
+                    *, max_len: int = 0,
+                    extra_embeds: jax.Array | None = None,
+                    full_cache: bool = False):
+    """``prefill`` that also returns the (token, expert) rows its held
+    experts computed, pad positions included: (logits, cache, rows), rows
+    None for a model without experts."""
     x = embed_tokens(params, tokens, cfg, ctx)
     if extra_embeds is not None:
         x = jnp.concatenate([extra_embeds.astype(x.dtype), x], axis=1)
@@ -590,20 +616,20 @@ def prefill(params, tokens, cfg: ModelConfig, ctx: RunCtx = RunCtx(), *,
     max_len = max_len or max(cfg.max_seq_len, S)
     positions = jnp.arange(S)[None, :]
     emb0 = x
-    aux = jnp.zeros((), jnp.float32)
+    rows = _no_rows(cfg)
     shared = params.get("shared_attn")
 
     dense_cache = []
     for blk in params.get("dense_layers", []):
         cap = min(max_len, cfg.sliding_window) \
             if (cfg.sliding_window and not full_cache) else max_len
-        x, c, aux = _block_prefill(blk, x, positions, cfg, ctx,
-                                   window=cfg.sliding_window,
-                                   cache_len=cap, aux=aux)
+        x, c, r = _block_prefill(blk, x, positions, cfg, ctx,
+                                 window=cfg.sliding_window, cache_len=cap)
+        rows = _add_rows(rows, r)
         dense_cache.append(c)
 
     def body(carry, unit):
-        x, aux = carry
+        x, rows = carry
         u = unit_size(cfg)
         unit_cache = {}
         for i in range(u):
@@ -619,19 +645,20 @@ def prefill(params, tokens, cfg: ModelConfig, ctx: RunCtx = RunCtx(), *,
                 w = cfg.window_for_layer(i)
                 cap = min(max_len, w) if (w > 0 and not full_cache) \
                     else max_len
-                x, c, aux = _block_prefill(sub, x, positions, cfg, ctx,
-                                           window=w, cache_len=cap, aux=aux)
+                x, c, r = _block_prefill(sub, x, positions, cfg, ctx,
+                                         window=w, cache_len=cap)
+                rows = _add_rows(rows, r)
                 unit_cache[f"sub{i}"] = c
         if shared is not None:
             h = jnp.concatenate([x, emb0], axis=-1) @ shared["w_in"].astype(x.dtype)
-            out, c, aux = _block_prefill(shared["block"], h, positions, cfg,
-                                         ctx, window=0, cache_len=max_len,
-                                         aux=aux)
+            out, c, r = _block_prefill(shared["block"], h, positions, cfg,
+                                       ctx, window=0, cache_len=max_len)
+            rows = _add_rows(rows, r)
             x = x + (out - h)
             unit_cache["__shared__"] = c
-        return (x, aux), unit_cache
+        return (x, rows), unit_cache
 
-    (x, aux), unit_caches = jax.lax.scan(body, (x, aux), params["layers"])
+    (x, rows), unit_caches = jax.lax.scan(body, (x, rows), params["layers"])
     x = _norm(x, params["final_norm"], cfg)
     logits = lm_logits(params, x, cfg, ctx)
 
@@ -642,7 +669,7 @@ def prefill(params, tokens, cfg: ModelConfig, ctx: RunCtx = RunCtx(), *,
         cache["shared"] = unit_caches["__shared__"]
     if dense_cache:
         cache["dense"] = dense_cache
-    return logits, cache
+    return logits, cache, rows
 
 
 def _block_decode(blk, x, pos, c, cfg: ModelConfig, ctx: RunCtx, *,
@@ -699,19 +726,19 @@ def _block_decode(blk, x, pos, c, cfg: ModelConfig, ctx: RunCtx, *,
         a = _norm(a, blk["post_attn_norm"], cfg)
     x = x + a
     h = _norm(x, blk["norm2"], cfg)
-    if "router" in blk["ffn"]:
-        f, _ = moe_forward(blk["ffn"], h, cfg, ctx.parallel,
-                           constrain=ctx.constrain)
-    else:
-        f = mlp_forward(blk["ffn"], h, cfg, constrain=ctx.constrain)
+    f, _, rows = _ffn(blk, h, cfg, ctx)
     if cfg.post_norms:
         f = _norm(f, blk["post_ffn_norm"], cfg)
-    return x + f, c
+    return x + f, c, rows
 
 
-def _paged_decode_step(params, cache, tokens, cfg: ModelConfig, ctx: RunCtx,
-                       active: jax.Array | None):
-    """decode_step over the paged cache layout: per-slot positions, block
+def paged_decode_step(params, cache, tokens, cfg: ModelConfig, ctx: RunCtx,
+                      active: jax.Array | None):
+    """decode_step over the paged cache layout, returning (logits, cache,
+    rows): ``rows`` counts the (token, expert) rows the held experts
+    computed, every slot included (None without experts).
+
+    Per-slot positions, block
     tables, shared page pools.  ``active`` (B,) gates the position advance —
     parked slots keep rewriting row ``pos[b]`` of their scratch page (or
     their private ring / state slot) and their sampled tokens are discarded
@@ -728,17 +755,21 @@ def _paged_decode_step(params, cache, tokens, cfg: ModelConfig, ctx: RunCtx,
     emb0 = x
     shared = params.get("shared_attn")
 
+    rows = _no_rows(cfg)
     new_dense = None
     if cfg.first_dense_layers:
         new_layers = []
         for j, blk in enumerate(params["dense_layers"]):
             c = jax.tree.map(lambda p: p[j], cache["dense"])
-            x, c2 = _block_decode(blk, x, pos, c, cfg, ctx,
-                                  window=cfg.sliding_window, block_tables=bt)
+            x, c2, r = _block_decode(blk, x, pos, c, cfg, ctx,
+                                     window=cfg.sliding_window,
+                                     block_tables=bt)
+            rows = _add_rows(rows, r)
             new_layers.append(c2)
         new_dense = jax.tree.map(lambda *xs: jnp.stack(xs), *new_layers)
 
-    def body(x, xs):
+    def body(carry, xs):
+        x, rows = carry
         unit, c_unit = xs
         new_c = {}
         for i in range(unit_size(cfg)):
@@ -757,8 +788,9 @@ def _paged_decode_step(params, cache, tokens, cfg: ModelConfig, ctx: RunCtx,
                                     "ssm": ssm_state.astype(c["ssm"].dtype)}
             else:
                 w = cfg.window_for_layer(i)
-                x, c2 = _block_decode(sub, x, pos, c, cfg, ctx, window=w,
-                                      block_tables=bt)
+                x, c2, r = _block_decode(sub, x, pos, c, cfg, ctx, window=w,
+                                         block_tables=bt)
+                rows = _add_rows(rows, r)
                 new_c[f"sub{i}"] = c2
         if shared is not None:
             # per-slot linear buffer behind a static identity table: slot
@@ -771,19 +803,21 @@ def _paged_decode_step(params, cache, tokens, cfg: ModelConfig, ctx: RunCtx,
             ps = Cs // nbs
             bt_id = jnp.arange(B * nbs, dtype=jnp.int32).reshape(B, nbs)
             pool = lambda p: p.reshape(B * nbs, ps, *p.shape[2:])
-            out, sc2 = _block_decode(shared["block"], h, pos,
-                                     {"k": pool(sc["k"]), "v": pool(sc["v"])},
-                                     cfg, ctx, window=0, block_tables=bt_id)
+            out, sc2, _ = _block_decode(
+                shared["block"], h, pos,
+                {"k": pool(sc["k"]), "v": pool(sc["v"])},
+                cfg, ctx, window=0, block_tables=bt_id)
             x = x + (out - h)
             new_c["__shared__"] = {"k": sc2["k"].reshape(sc["k"].shape),
                                    "v": sc2["v"].reshape(sc["v"].shape)}
-        return x, new_c
+        return (x, rows), new_c
 
     units_cache = cache["units"]
     if shared is not None:
         units_cache = dict(units_cache)
         units_cache["__shared__"] = cache["shared"]
-    x, new_units = jax.lax.scan(body, x, (params["layers"], units_cache))
+    (x, rows), new_units = jax.lax.scan(body, (x, rows),
+                                        (params["layers"], units_cache))
     x = _norm(x, params["final_norm"], cfg)
     logits = lm_logits(params, x, cfg, ctx)
     adv = jnp.ones_like(pos) if active is None \
@@ -795,7 +829,7 @@ def _paged_decode_step(params, cache, tokens, cfg: ModelConfig, ctx: RunCtx,
         new_cache["shared"] = new_units["__shared__"]
     if new_dense is not None:
         new_cache["dense"] = new_dense
-    return logits, new_cache
+    return logits, new_cache, rows
 
 
 def speculative_blockers(cfg: ModelConfig) -> tuple[str, ...]:
@@ -865,21 +899,24 @@ def _block_verify(blk, x, pos, c, cfg: ModelConfig, ctx: RunCtx, *,
         a = _norm(a, blk["post_attn_norm"], cfg)
     x = x + a
     h = _norm(x, blk["norm2"], cfg)
-    if "router" in blk["ffn"]:
-        f, _ = moe_forward(blk["ffn"], h, cfg, ctx.parallel,
-                           constrain=ctx.constrain)
-    else:
-        f = mlp_forward(blk["ffn"], h, cfg, constrain=ctx.constrain)
+    f, _, rows = _ffn(blk, h, cfg, ctx)
     if cfg.post_norms:
         f = _norm(f, blk["post_ffn_norm"], cfg)
     pend = {"lat": lat_new} if kv_new is None \
         else {"k": kv_new[0], "v": kv_new[1]}
-    return x + f, pend
+    return x + f, pend, rows
 
 
 def verify_step(params, cache, tokens, cfg: ModelConfig,
                 ctx: RunCtx = RunCtx()):
-    """Score ``Q = K+1`` speculative tokens in ONE cache sweep.
+    """(logits, pending) of ``verify_counted``, which see."""
+    return verify_counted(params, cache, tokens, cfg, ctx)[:2]
+
+
+def verify_counted(params, cache, tokens, cfg: ModelConfig,
+                   ctx: RunCtx = RunCtx()):
+    """Score ``Q = K+1`` speculative tokens in ONE cache sweep; returns
+    (logits, pending, rows the held experts computed | None).
 
     tokens: (B, Q) — the fed block [t_last, d_1..d_K] at positions
     ``pos .. pos+Q-1``.  Returns (logits (B, Q, V), pending) where
@@ -907,34 +944,40 @@ def verify_step(params, cache, tokens, cfg: ModelConfig,
     bt = cache.get("block_tables")
     x = embed_tokens(params, tokens, cfg, ctx)
 
+    rows = _no_rows(cfg)
     pend_dense = None
     if cfg.first_dense_layers:          # paged-only: the ring gate names it
         layer_pend = []
         for j, blk in enumerate(params["dense_layers"]):
             c = jax.tree.map(lambda p: p[j], cache["dense"])
-            x, p = _block_verify(blk, x, pos, c, cfg, ctx,
-                                 window=cfg.sliding_window, block_tables=bt)
+            x, p, r = _block_verify(blk, x, pos, c, cfg, ctx,
+                                    window=cfg.sliding_window,
+                                    block_tables=bt)
+            rows = _add_rows(rows, r)
             layer_pend.append(p)
         pend_dense = jax.tree.map(lambda *xs: jnp.stack(xs), *layer_pend)
 
-    def body(x, xs):
+    def body(carry, xs):
+        x, rows = carry
         unit, c_unit = xs
         pend = {}
         for i in range(unit_size(cfg)):
             sub, c = unit[f"sub{i}"], c_unit[f"sub{i}"]
             window = 0 if paged else cfg.window_for_layer(i)
-            x, p = _block_verify(sub, x, pos, c, cfg, ctx, window=window,
-                                 block_tables=bt)
+            x, p, r = _block_verify(sub, x, pos, c, cfg, ctx, window=window,
+                                    block_tables=bt)
+            rows = _add_rows(rows, r)
             pend[f"sub{i}"] = p
-        return x, pend
+        return (x, rows), pend
 
-    x, pending = jax.lax.scan(body, x, (params["layers"], cache["units"]))
+    (x, rows), pending = jax.lax.scan(body, (x, rows),
+                                      (params["layers"], cache["units"]))
     x = _norm(x, params["final_norm"], cfg)
     logits = lm_logits(params, x, cfg, ctx)
     if pend_dense is not None:
         pending = dict(pending)
         pending["__dense__"] = pend_dense
-    return logits, pending
+    return logits, pending, rows
 
 
 def commit_spec(cache, pending, n_accept, cfg: ModelConfig):
@@ -980,13 +1023,15 @@ def prefill_suffix(params, cache, tokens, n_commit, cfg: ModelConfig,
     write nor advance — so one fixed-shape executable serves every join
     against the live engine cache without touching the other slots.
 
-    Returns ``(logits, cache)``: row ``n_commit[b] - 1`` of slot b's
+    Returns ``(logits, cache, rows)``: row ``n_commit[b] - 1`` of slot b's
     logits scores the token after its last real suffix token (the
-    engine's first-token sample on a full-suffix join)."""
-    logits, pending = verify_step(params, cache, tokens, cfg, ctx)
+    engine's first-token sample on a full-suffix join); ``rows`` counts
+    the (token, expert) rows the held experts computed, pad rows and
+    other slots' rows included (None without experts)."""
+    logits, pending, rows = verify_counted(params, cache, tokens, cfg, ctx)
     active = (n_commit > 0).astype(jnp.int32)
     new_cache = commit_spec_paged(cache, pending, n_commit - 1, active, cfg)
-    return logits, new_cache
+    return logits, new_cache, rows
 
 
 def commit_spec_paged(cache, pending, n_accept, active, cfg: ModelConfig):
@@ -1059,7 +1104,7 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, ctx: RunCtx = RunCtx(),
     (per-slot ``pos``/``block_tables``, shared page pools).  ``active``
     applies to the paged layout only: it gates which slots advance."""
     if "block_tables" in cache:
-        return _paged_decode_step(params, cache, tokens, cfg, ctx, active)
+        return paged_decode_step(params, cache, tokens, cfg, ctx, active)[:2]
     pos = cache["pos"]
     x = embed_tokens(params, tokens, cfg, ctx)
     emb0 = x
@@ -1067,7 +1112,8 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, ctx: RunCtx = RunCtx(),
 
     new_dense = []
     for blk, c in zip(params.get("dense_layers", []), cache.get("dense", [])):
-        x, c = _block_decode(blk, x, pos, c, cfg, ctx, window=cfg.sliding_window)
+        x, c, _ = _block_decode(blk, x, pos, c, cfg, ctx,
+                                window=cfg.sliding_window)
         new_dense.append(c)
 
     def body(x, xs):
@@ -1085,12 +1131,13 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, ctx: RunCtx = RunCtx(),
                                     "ssm": ssm_state.astype(c["ssm"].dtype)}
             else:
                 window = cfg.window_for_layer(i)
-                x, c2 = _block_decode(sub, x, pos, c, cfg, ctx, window=window)
+                x, c2, _ = _block_decode(sub, x, pos, c, cfg, ctx,
+                                         window=window)
                 new_c[f"sub{i}"] = c2
         if shared is not None:
             h = jnp.concatenate([x, emb0], axis=-1) @ shared["w_in"].astype(x.dtype)
             sc = c_unit["__shared__"]
-            out, sc2 = _block_decode(shared["block"], h, pos, sc, cfg, ctx,
+            out, sc2, _ = _block_decode(shared["block"], h, pos, sc, cfg, ctx,
                                      window=0)
             x = x + (out - h)
             new_c["__shared__"] = sc2

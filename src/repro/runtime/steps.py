@@ -209,7 +209,9 @@ def _decode_loop_impl(params, cache, tokens, active, key, *, cfg, ctx,
     """Shared fused-loop body: ``n_tokens`` decode steps (model forward,
     sampling, cache update) in ONE ``lax.scan``.  ``active`` is None for
     the ring layout; for the paged layout it gates the per-slot position
-    advance (see ``make_paged_decode_loop``)."""
+    advance (see ``make_paged_decode_loop``), and the loop also returns
+    the rows its held experts computed over the steps (None without
+    experts)."""
     if greedy:
         keys = None                            # no PRNG work on the hot path
     else:
@@ -219,19 +221,24 @@ def _decode_loop_impl(params, cache, tokens, active, key, *, cfg, ctx,
 
     def body(carry, key_t):
         cache, tok = carry
-        logits, cache = tfm.decode_step(params, cache, tok, cfg, ctx,
-                                        active=active)
+        if active is None:
+            logits, cache = tfm.decode_step(params, cache, tok, cfg, ctx)
+            rows = None
+        else:
+            logits, cache, rows = tfm.paged_decode_step(params, cache, tok,
+                                                        cfg, ctx, active)
         last = logits[:, -1]                   # (B, V) or (B, n_cb, V)
         if greedy:
             nxt = jnp.argmax(last, axis=-1)
         else:
             nxt = jax.random.categorical(key_t, last / temperature, axis=-1)
         nxt = nxt.astype(jnp.int32)
-        return (cache, nxt[:, None]), nxt
+        return (cache, nxt[:, None]), (nxt, rows)
 
-    (cache, _), toks = jax.lax.scan(body, (cache, tokens), keys,
-                                    length=n_tokens)
-    return jnp.moveaxis(toks, 0, 1), cache     # (B, n_tokens[, n_cb])
+    (cache, _), (toks, rows) = jax.lax.scan(body, (cache, tokens), keys,
+                                            length=n_tokens)
+    rows = None if rows is None else jnp.sum(rows)
+    return jnp.moveaxis(toks, 0, 1), cache, rows   # (B, n_tokens[, n_cb])
 
 
 def make_decode_loop(cfg: ModelConfig, step_cfg: StepConfig,
@@ -254,14 +261,15 @@ def make_decode_loop(cfg: ModelConfig, step_cfg: StepConfig,
     def decode_loop(params, cache, tokens, key=None):
         return _decode_loop_impl(params, cache, tokens, None, key, cfg=cfg,
                                  ctx=ctx, n_tokens=n_tokens, greedy=greedy,
-                                 temperature=temperature)
+                                 temperature=temperature)[:2]
 
     return decode_loop
 
 
 def make_prefill_suffix_step(cfg: ModelConfig, step_cfg: StepConfig,
                              rules: ShardingRules | None = None) -> Callable:
-    """suffix_step(params, cache, tokens, n_commit) -> (logits, cache).
+    """suffix_step(params, cache, tokens, n_commit) -> (logits, cache,
+    expert rows | None).
 
     One chunked-paged-prefill sweep (see ``transformer.prefill_suffix``):
     ``tokens`` is (n_slots, chunk) with the joining slot's row holding the
@@ -454,7 +462,9 @@ def make_paged_decode_loop(cfg: ModelConfig, step_cfg: StepConfig,
                            n_tokens: int = 16, *, greedy: bool = True,
                            temperature: float = 1.0) -> Callable:
     """decode_loop(params, cache, tokens, active, key=None)
-    -> (token_block, cache) over the *paged* cache layout.
+    -> (token_block, cache, expert rows | None) over the *paged* cache
+    layout; the rows are those the held experts computed over the chunk,
+    parked slots included.
 
     The continuous-batching engine's inner loop: ``cache`` comes from
     ``transformer.init_paged_cache`` (per-slot positions + block tables +

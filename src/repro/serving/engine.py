@@ -193,6 +193,11 @@ class EngineReport:
     transfer_j: float = 0.0
     n_demotions: int = 0          # device pages paged out to the host tier
     n_promotions: int = 0         # host pages paged back in on a prefix hit
+    # (token, expert) rows the held experts computed, counted on the device
+    # and fetched with each chunk's tokens / each join's first token: pad
+    # and parked-slot rows included, speculative chunks not counted
+    expert_rows_decode: int = 0
+    expert_rows_prefill: int = 0
 
     @property
     def tok_per_s(self) -> float:
@@ -431,8 +436,8 @@ class ServeEngine:
                 # full_cache: keep windowed layers linear so the bucket's
                 # pad rows can't wrap over the real window tail before the
                 # inject scatter reads it
-                return tfm.prefill(params, inputs, cfg, ctx, max_len=bucket,
-                                   full_cache=True)
+                return tfm.prefill_counted(params, inputs, cfg, ctx,
+                                           max_len=bucket, full_cache=True)
 
             self._prefills[bucket] = jax.jit(prefill)
         return self._prefills[bucket]
@@ -657,13 +662,14 @@ class ServeEngine:
         """Chunked paged prefill of the uncached suffix ``prompt[m:]``:
         fixed-shape verify sweeps against the slot's (partly shared)
         pages, committed rows advancing ``pos`` in place.  Returns the
-        logits row scoring the token after the prompt's last token."""
+        logits row scoring the token after the prompt's last token, and
+        each sweep's expert-row count (device scalars, or None)."""
         ecfg = self.ecfg
         L = req.prompt_len
         suffix = np.asarray(req.prompt[m:])
         qc = ecfg.prefill_chunk
         tok_shape = (ecfg.n_slots, qc) + suffix.shape[1:]
-        logits, r = None, 0
+        logits, r, rows = None, 0, []
         for c0 in range(0, L - m, qc):
             r = min(qc, L - m - c0)
             tok = np.zeros(tok_shape, np.int32)
@@ -676,25 +682,33 @@ class ServeEngine:
                           "block_tables": jnp.asarray(self.kv.tables)}
             args = (self.params, self.cache, jnp.asarray(tok),
                     jnp.asarray(ncommit))
-            logits, self.cache = self._suffix_step(args)(*args)
-        return logits[slot, r - 1]
+            logits, self.cache, n = self._suffix_step(args)(*args)
+            rows.append(n)
+        return logits[slot, r - 1], rows
 
     # -- join ----------------------------------------------------------------
     def _sweeps(self, L: int, m: int) -> int:
         """Chunked-prefill sweeps a join restoring ``m`` of ``L`` runs."""
         return -(-(L - m) // self.ecfg.prefill_chunk) if m > 0 else 0
 
-    def _sample_first(self, logits_row, rid: int):
+    def _sample_first(self, logits_row, rid: int, rows):
         """Sample the prefill's token (greedy or temperature) — position
-        prompt_len - 1 of the padded prefill logits."""
+        prompt_len - 1 of the padded prefill logits — and fetch it with
+        the join's expert-row counts (device scalars or None) in one
+        transfer: (token, rows summed)."""
         if self.ecfg.greedy:
-            return np.asarray(jnp.argmax(logits_row, axis=-1), np.int32)
-        key = jax.random.fold_in(self._sample_key, (rid << 1) | 1)
-        nxt = jax.random.categorical(
-            key, logits_row / self.ecfg.temperature, axis=-1)
-        return np.asarray(nxt, np.int32)
+            nxt = jnp.argmax(logits_row, axis=-1)
+        else:
+            key = jax.random.fold_in(self._sample_key, (rid << 1) | 1)
+            nxt = jax.random.categorical(
+                key, logits_row / self.ecfg.temperature, axis=-1)
+        nxt, rows = jax.device_get((nxt, rows))
+        return (np.asarray(nxt, np.int32),
+                sum(int(n) for n in rows if n is not None))
 
-    def _join(self, slot: int, req: Request, m: int, copy, t0: float) -> None:
+    def _join(self, slot: int, req: Request, m: int, copy, t0: float) -> int:
+        """Join ``req`` into ``slot``; returns the expert rows its prefill
+        computed."""
         L = req.prompt_len
         if L + req.max_new_tokens > self.ecfg.max_len:
             raise ValueError(f"request {req.rid}: prompt {L} + "
@@ -714,7 +728,7 @@ class ServeEngine:
             # prefill ONLY the uncached suffix, through the paged verify
             # sweep (chunked, fixed-shape, in-place commit)
             with self._span("suffix_prefill") as s:
-                logits_row = self._prefill_suffix(slot, req, m)
+                logits_row, expert_rows = self._prefill_suffix(slot, req, m)
             if s is not None:
                 s.counts["sweeps"] = self._sweeps(L, m)
         else:
@@ -729,16 +743,18 @@ class ServeEngine:
             inputs = np.concatenate(
                 [req.prompt[None], np.zeros(pad_shape, np.int32)], axis=1)
             with self._span("prefill"):
-                logits, pcache = self._prefill(bucket)(self.params,
-                                                       jnp.asarray(inputs))
-                logits_row = logits[0, L - 1]
+                logits, pcache, n = self._prefill(bucket)(
+                    self.params, jnp.asarray(inputs))
+                logits_row, expert_rows = logits[0, L - 1], [n]
             with self._span("inject"):
                 rows = {k: jnp.asarray(v) for k, v in
                         self._inject_rows_tree(slot, bucket, L).items()}
                 self.cache = self._inject(bucket)(
                     self.cache, pcache, rows, jnp.asarray(slot, jnp.int32))
         with self._span("sample_first"):     # the join's device sync
-            first = self._sample_first(logits_row, req.rid)
+            first, n_rows = self._sample_first(logits_row, req.rid,
+                                               expert_rows)
+        self._report.expert_rows_prefill += n_rows
         self._pos[slot] = L
         if self._use_prefix:
             # index the prompt's (now fully written) pages for future joins
@@ -773,6 +789,7 @@ class ServeEngine:
             res.finish_t = time.perf_counter() - t0
             self.scheduler.finish(slot)
             self._pos[slot] = 0
+        return n_rows
 
     # -- preemption ----------------------------------------------------------
     def _preempt(self, slot: int, t0: float) -> None:
@@ -1126,12 +1143,14 @@ class ServeEngine:
                                     queue_depth=len(queue))
                 for slot, req, m, copy in joins:
                     with self._span("join", req.rid) as s:
-                        self._join(slot, req, m, copy, t0)
+                        n_rows = self._join(slot, req, m, copy, t0)
                     if s is not None:
                         s.counts.update(prompt_tokens=req.prompt_len,
                                         restored=m,
                                         sweeps=self._sweeps(req.prompt_len,
                                                             m))
+                        if self.cfg.uses_moe:
+                            s.counts["expert_rows"] = n_rows
             # emergency cap: halve the decode chunk, drop speculation — the
             # chunk's compute shrinks instead of violating the cap
             eff_chunk = ecfg.decode_chunk if degraded < 2 \
@@ -1203,14 +1222,16 @@ class ServeEngine:
             t_c = time.perf_counter()
             if sp is not None:
                 sp.switch("dispatch", t_c)
+            rows = None
             if spec:
                 toks, counts, self.cache, dstate = loop(*args)
             else:
-                toks, self.cache = loop(*args)
+                toks, self.cache, rows = loop(*args)
             t_d = time.perf_counter()
             if sp is not None:
                 sp.switch("device_wait", t_d)
-            toks = np.asarray(jax.block_until_ready(toks))
+            toks, rows = jax.device_get((toks, rows))
+            toks = np.asarray(toks)
             if spec:
                 counts = np.asarray(counts)
                 # np.array (not asarray): seed_row mutates this mirror on join
@@ -1250,10 +1271,13 @@ class ServeEngine:
                                clock_step=self._now,
                                degrade_level=degraded)
             energy = self.on_chunk(stats) if self.on_chunk is not None else None
+            n_rows = {} if rows is None else {"expert_rows": int(rows)}
+            report.expert_rows_decode += n_rows.get("expert_rows", 0)
             if sp is not None:
                 sp.close()
                 sp.close(n_active=n_active, tokens_kept=kept,
-                         finished=n_active - self.scheduler.n_active)
+                         finished=n_active - self.scheduler.n_active,
+                         **n_rows)
             report.n_chunks += 1
             report.decode_wall_s += wall
             report.tokens_kept += kept

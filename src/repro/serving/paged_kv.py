@@ -305,7 +305,12 @@ class PagedKVCache:
         """Longest cached prefix of ``tokens``, capped at ``len - 1`` (at
         least one token is always left to prefill so its logits exist).
         Returns (full_nodes, partial) where partial is (node, n_rows) for a
-        match ending inside a page, or None."""
+        match ending inside a page, or None.
+
+        A match shorter than one page is not taken: it would save fewer
+        than ``page_size`` tokens and send the whole prompt through the
+        chunked suffix sweeps in place of one cold prefill.  Unrelated
+        prompts that share their first token or two match that way."""
         t = np.asarray(tokens)
         max_share = len(t) - 1
         node, full = self._root, []
@@ -320,7 +325,7 @@ class PagedKVCache:
         off = len(full) * self.page_size
         rem = min(self.page_size, max_share - off)
         partial = None
-        if rem > 0:
+        if rem > 0 and full:
             want = np.asarray(t[off:off + rem]).reshape(rem, -1)
             best, best_n = None, 0
             for child in node.children.values():

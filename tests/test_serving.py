@@ -294,6 +294,27 @@ def test_paged_kv_prefix_sharing_unit(smollm):
     assert kv2.n_free == 0 and kv2.n_evictable() == 0
 
 
+def test_paged_kv_prefix_match_needs_a_whole_page(smollm):
+    """A prompt that shares fewer than page_size leading tokens with a
+    cached one restores nothing and copies nothing: it prefills cold,
+    where the match would have sent it through the suffix sweeps."""
+    cfg, _ = smollm
+    kv = PagedKVCache(cfg, n_slots=2, page_size=4, max_len=32, n_pages=12)
+    tokens = np.arange(12, dtype=np.int32)
+    kv.admit_with_prefix(0, tokens, 12)
+    kv.register_prefix(0, tokens)
+    kv.release(0)
+    for shared in (1, 3):
+        other = np.concatenate([tokens[:shared], np.full(9, 77, np.int32)])
+        m, copy = kv.admit_with_prefix(1, other, 12)
+        assert (m, copy) == (0, None), shared
+        kv.release(1)
+    m, copy = kv.admit_with_prefix(1, tokens[:6], 6)   # a page and a row
+    assert m == 5 and copy is not None and copy.n_rows == 1
+    kv.copy_done(copy.src_page)
+    kv.release(1)
+
+
 def test_paged_kv_manager_invariants(smollm):
     cfg, _ = smollm
     kv = PagedKVCache(cfg, n_slots=2, page_size=4, max_len=32, n_pages=8)

@@ -8,6 +8,7 @@ tests and only the worker given this file loads the TPU library.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -236,3 +237,45 @@ def test_smollm_suffix_step_names_its_kernel(one_chip):
                           _shape(one_chip, (B,), jnp.int32)).compile()
     assert compiled.as_text().startswith("HloModule jit_suffix_step")
     assert _kernels(compiled) == {"paged_verify_sweep"}
+
+
+def test_deepseek_lite_expert_layer_compiles_to_grouped_matmuls(one_chip):
+    """DeepSeek-V2-Lite's routed-expert layer at its published widths and
+    the decode shapes of its benchmark cell (64 slots, 8 of 64 experts
+    held): three grouped matmuls, with the operand and result shapes the
+    benchmark's ``moe_roofline.long_batch`` reader picks them by, and no
+    dense all-expert matmul."""
+    import re
+    from repro.models.layers import moe_forward
+    cfg = dataclasses.replace(get_arch("deepseek-v2-lite").config,
+                              held_experts=8)
+    ffn = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: tfm.init_lm(jax.random.PRNGKey(0),
+                                           cfg)[0])["layers"]["sub0"]["ffn"])
+    compiled = _compile(lambda p, x: moe_forward(p, x, cfg)[::2], ffn,
+                        _shape(one_chip, (64, 1, 2048), jnp.bfloat16))
+    text = _hlo_with_operand_shapes(compiled)
+    rows, d, ff = 64 * 6, 2048, 1408
+    shape = r"bf16\[%d,%d\]\{[^}]*\} custom-call\(.*"
+    gate_up = re.compile(r"= " + shape % (rows, ff)
+                         + r"bf16\[8,%d,%d\]" % (d, ff))
+    down = re.compile(r"= " + shape % (rows, d) + r"bf16\[8,%d,%d\]" % (ff, d))
+    lines = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sum(1 for ln in lines if gate_up.search(ln)) == 2
+    assert sum(1 for ln in lines if down.search(ln)) == 1
+    assert f"[8,{rows},{ff}]" not in text
+
+
+def test_deepseek_lite_latent_sweep_compiles_at_the_cells_batch(one_chip):
+    """The latent sweep at DeepSeek-V2-Lite's 16 heads, 64 slots x 4096
+    tokens in pages of 16, as ``mla_roofline.long_batch`` names it."""
+    S = lambda shape, dt=jnp.bfloat16: _shape(one_chip, shape, dt)
+    compiled = _compile(
+        lambda q, lat, t, p: da.mla_paged_decode_attention_pallas(
+            q, lat, t, p, r_kv=MLA_RKV, n_splits=1, scale=192 ** -0.5),
+        S((64, 1, 16, MLA_R)), S((64 * 256 + 64, PS, MLA_R)),
+        S((64, 256), jnp.int32), S((64,), jnp.int32))
+    assert _kernels(compiled) == {"mla_paged_decode_sweep"}
